@@ -85,6 +85,9 @@ def _shard_parity_rows() -> list[tuple[str, float, str]]:
     single = _parity_log(mesh=None)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # the child simulates 8 devices on the host CPU; it must never reach
+    # for the accelerator, which this process already holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep

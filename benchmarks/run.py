@@ -328,6 +328,11 @@ def main() -> None:
             from .gates import run_suite
             run_suite(argv[1:])
             return
+        # the gate suite's parent never imports JAX (each gate's child
+        # process takes the device in turn); only the paths that run
+        # benches compile, so only they turn the compile cache on
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache()
         if argv and argv[0] == "--update-baseline":
             update_baseline(argv[1:])
             return

@@ -92,9 +92,8 @@ only for unknown density specs.
 
 When a candidate axis is large and several devices are visible,
 ``evaluate(..., mesh=...)`` shards the population across the mesh with
-``shard_map`` (the version shim in ``runtime/compression.py``): each
-device vmaps its slice of the population, so mapspace sweeps scale
-linearly with device count.
+``jax.shard_map``: each device vmaps its slice of the population, so
+mapspace sweeps scale linearly with device count.
 
 Every traced-program construction and every first-evaluation-at-a-shape
 (the moments XLA actually compiles) is counted by
@@ -111,7 +110,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from .. import obs
 from . import compile_stats
@@ -119,8 +118,8 @@ from .arch import (COMPUTE_FIELDS, STORAGE_FIELDS, ArchParams,
                    Architecture, arch_structure, pack_arch_params,
                    topology_key)
 from .density import (ACTUAL_ID, BatchedDensityUnsupported, DensityCaps,
-                      DensityModel, TracedDensityStats, caps_for_models,
-                      make_density_model)
+                      DensityModel, HyperBatch, TracedDensityStats,
+                      caps_for_models, make_density_model)
 from .mapping import Loop, LoopNest
 from .taxonomy import RankFormat, SAFSpec, SAFKind
 from .workload import TensorSpec, Workload
@@ -529,13 +528,11 @@ class _ProgramRecord:
             fn = self.sharded_fns.get(key)
             if fn is None:
                 from jax.sharding import PartitionSpec as P
-
-                from ..runtime.compression import shard_map
                 # batch args (bounds, rank ids, per-candidate arch rows)
                 # shard their leading (candidate) axis; the workload
                 # params are replicated on every device
                 spec = P(mesh.axis_names[0])
-                fn = jax.jit(shard_map(
+                fn = jax.jit(jax.shard_map(
                     jax.vmap(self.single, in_axes=(0, None)),
                     mesh=mesh, in_specs=(spec, P()), out_specs=spec,
                     check_vma=False))
@@ -750,6 +747,10 @@ class _TracedNestModel:
     # scalar model must be reflected here (the parity suites pin it).
     # ------------------------------------------------------------------
     def _single(self, b, oh, wp, ap):
+        return HyperBatch.run(
+            lambda hyper: self._single_pass(b, oh, wp, ap, hyper))
+
+    def _single_pass(self, b, oh, wp, ap, hyper):
         wl = self.workload
         levels = self.slot_levels
         S = self.arch.num_levels
@@ -768,7 +769,8 @@ class _TracedNestModel:
 
         def d_pe(name, tile):
             i = tidx[name]
-            return stats.prob_empty(mids[i], dparams[i], hists[i], tile)
+            return stats.prob_empty(mids[i], dparams[i], hists[i], tile,
+                                    hyper)
 
         def d_ed(name, tile):
             i = tidx[name]

@@ -44,6 +44,14 @@ rows for every aligned 1-D tile size, precomputed once from the array
 size at evaluation time.  Shape-dependent statistics (banded row scans,
 histogram tables) are padded to static :class:`DensityCaps` so programs
 stay shape-stable across layers.
+
+The uniform and structured ``prob_empty`` forms share one hypergeometric
+term, ``exp(log C(a - b, t) - log C(a, t))``.  The switch returns that
+term's arguments and the term itself is evaluated outside it, so a whole
+traced program can evaluate every such term in ONE ``gammaln``/``exp``
+op (:class:`HyperBatch`).  The TPU has no float64 unit: it emulates each
+float64 ``lgamma`` with a long expansion that costs seconds of compile
+per call site, and a bucket program has hundreds of sites.
 """
 from __future__ import annotations
 
@@ -68,13 +76,17 @@ def _log_comb(n: float, k: float) -> float:
     return (math.lgamma(n + 1) - math.lgamma(k + 1) - math.lgamma(n - k + 1))
 
 
-def _log_comb_b(n, k):
-    """Traceable log C(n, k) for jnp array inputs; -inf when invalid."""
+def _log_comb_args(n, k):
+    """The three ``gammaln`` arguments of log C(n, k)."""
+    return (n + 1.0, k + 1.0, n - k + 1.0)
+
+
+def _log_comb_of(n, k, g):
+    """log C(n, k) from ``g``, the ``gammaln`` of its three arguments;
+    -inf when invalid."""
     import jax.numpy as jnp
-    from jax.scipy.special import gammaln
     valid = (k >= 0) & (k <= n) & (n >= 0)
-    out = gammaln(n + 1.0) - gammaln(k + 1.0) - gammaln(n - k + 1.0)
-    return jnp.where(valid, out, -jnp.inf)
+    return jnp.where(valid, g[0] - g[1] - g[2], -jnp.inf)
 
 
 class BatchedDensityUnsupported(NotImplementedError):
@@ -147,10 +159,41 @@ def caps_for_models(models: Sequence["DensityModel"],
 # All are pure jnp closed forms — the single source of truth for both
 # the instance ``*_b`` wrappers and the TracedDensityStats switch.
 # ----------------------------------------------------------------------
-def dense_prob_empty_t(p, h, t):
+def hyper_empty_t(a, b, t):
+    """P(a t-element draw without replacement from ``a`` elements misses
+    all ``b`` marked ones): the hypergeometric empty-tile term."""
+    import jax.numpy as jnp
+    from jax.scipy.special import gammaln
+    a, b, t = jnp.broadcast_arrays(a, b, t)
+    # all six gammaln terms in one op (see the module docstring)
+    g = gammaln(jnp.stack(_log_comb_args(a - b, t) + _log_comb_args(a, t)))
+    return jnp.exp(_log_comb_of(a - b, t, g[:3]) - _log_comb_of(a, t, g[3:]))
+
+
+def _combine_pe(direct, a, b, t, use_hyper, hyper=hyper_empty_t):
+    """A ``*_prob_empty_split_t`` tuple -> the probability."""
+    import jax.numpy as jnp
+    return jnp.where(use_hyper, hyper(a, b, t), direct)
+
+
+# ``*_prob_empty_split_t(p, h, t)`` -> (direct, a, b, t', use_hyper): the
+# statistic is ``hyper_empty_t(a, b, t')`` where ``use_hyper`` holds and
+# ``direct`` elsewhere.  Kinds without the term return use_hyper=False
+# and inert (1, 0, 0) arguments.
+def _no_hyper(direct):
+    import jax.numpy as jnp
+    one = jnp.ones_like(direct)
+    return direct, one, 0.0 * one, 0.0 * one, jnp.zeros_like(direct, bool)
+
+
+def dense_prob_empty_split_t(p, h, t):
     import jax.numpy as jnp
     del p, h
-    return jnp.zeros_like(t * 1.0)
+    return _no_hyper(jnp.zeros_like(t * 1.0))
+
+
+def dense_prob_empty_t(p, h, t):
+    return _combine_pe(*dense_prob_empty_split_t(p, h, t))
 
 
 def dense_expected_density_t(p, h, t):
@@ -164,13 +207,17 @@ def dense_max_nnz_t(p, h, t):
     return t * 1.0
 
 
-def uniform_prob_empty_t(p, h, t):
+def uniform_prob_empty_split_t(p, h, t):
     """params: [tensor_size, nnz, density, -]."""
     import jax.numpy as jnp
     del h
     S, N = p[0], p[1]
     T = jnp.minimum(t * 1.0, S)
-    return jnp.exp(_log_comb_b(S - N, T) - _log_comb_b(S, T))
+    return jnp.zeros_like(T), S, N, T, jnp.ones_like(T, bool)
+
+
+def uniform_prob_empty_t(p, h, t):
+    return _combine_pe(*uniform_prob_empty_split_t(p, h, t))
 
 
 def uniform_expected_density_t(p, h, t):
@@ -185,14 +232,17 @@ def uniform_max_nnz_t(p, h, t):
     return jnp.minimum(t * 1.0, p[1])
 
 
-def structured_prob_empty_t(p, h, t):
+def structured_prob_empty_split_t(p, h, t):
     """params: [tensor_size, n, m, -]."""
     import jax.numpy as jnp
     del h
     n, m = p[1], p[2]
     tt = t * 1.0
-    lp = _log_comb_b(m - n, tt) - _log_comb_b(m, tt)
-    return jnp.where(tt >= m - n + 1, 0.0, jnp.exp(lp))
+    return jnp.zeros_like(tt), m, n, tt, ~(tt >= m - n + 1)
+
+
+def structured_prob_empty_t(p, h, t):
+    return _combine_pe(*structured_prob_empty_split_t(p, h, t))
 
 
 def structured_expected_density_t(p, h, t):
@@ -298,12 +348,64 @@ def actual_prob_empty_t(p, h, t):
     return h[0, _actual_index(p, t)]
 
 
+def _split_of(prob_empty_t):
+    """Split form of a kind whose prob_empty has no hypergeometric term."""
+    return lambda p, h, t: _no_hyper(prob_empty_t(p, h, t) * 1.0)
+
+
 def actual_expected_density_t(p, h, t):
     return h[1, _actual_index(p, t)]
 
 
 def actual_max_nnz_t(p, h, t):
     return h[2, _actual_index(p, t)]
+
+
+class HyperBatch:
+    """Evaluates every hypergeometric term of one traced function in a
+    single ``gammaln``/``exp`` op, by tracing the function twice:
+
+        HyperBatch.run(lambda hyper: body(..., hyper))
+
+    The first trace records each term's arguments and yields
+    placeholders; the terms are then evaluated together, and the second
+    trace replays their values in call order.  The first trace's outputs
+    are unused, so the compiler drops them.  The result is the same,
+    element by element, as evaluating each term where it is called; the
+    tile sizes a term is asked about never depend on an earlier term."""
+
+    def __init__(self):
+        self._args: list = []
+        self._vals = None
+
+    def __call__(self, a, b, t):
+        import jax.numpy as jnp
+        a, b, t = jnp.broadcast_arrays(a, b, t)
+        if self._vals is None:
+            self._args.append((a, b, t))
+            return jnp.zeros(a.shape, a.dtype)
+        return next(self._vals)
+
+    def _solve(self) -> None:
+        import jax.numpy as jnp
+        if not self._args:
+            self._vals = iter(())
+            return
+        flat = [jnp.concatenate([jnp.ravel(x[i]) for x in self._args])
+                for i in range(3)]
+        vals = hyper_empty_t(*flat)
+        out, off = [], 0
+        for a, _, _ in self._args:
+            out.append(vals[off:off + a.size].reshape(a.shape))
+            off += a.size
+        self._vals = iter(out)
+
+    @classmethod
+    def run(cls, fn):
+        hyper = cls()
+        fn(hyper)
+        hyper._solve()
+        return fn(hyper)
 
 
 class TracedDensityStats:
@@ -314,7 +416,9 @@ class TracedDensityStats:
     mixed density kinds and the kind itself is workload data.  Branches
     whose static capacity is zero (no banded / no actual tensor can ever
     be selected) are pruned to the trivial dense form so pure-statistical
-    programs pay nothing for them."""
+    programs pay nothing for them.  ``prob_empty`` switches on the split
+    forms and evaluates the hypergeometric term after the switch, through
+    ``hyper`` (a :class:`HyperBatch` inside the engine's program)."""
 
     def __init__(self, caps: DensityCaps):
         self.caps = caps
@@ -324,12 +428,12 @@ class TracedDensityStats:
         def with_caps(fn):
             return lambda p, h, t: fn(p, h, t, caps)
 
-        self._pe = (dense_prob_empty_t, uniform_prob_empty_t,
-                    structured_prob_empty_t,
-                    with_caps(banded_prob_empty_t) if banded_ok
-                    else dense_prob_empty_t,
-                    actual_prob_empty_t if actual_ok
-                    else dense_prob_empty_t)
+        self._pe = (dense_prob_empty_split_t, uniform_prob_empty_split_t,
+                    structured_prob_empty_split_t,
+                    _split_of(with_caps(banded_prob_empty_t)) if banded_ok
+                    else dense_prob_empty_split_t,
+                    _split_of(actual_prob_empty_t) if actual_ok
+                    else dense_prob_empty_split_t)
         self._ed = (dense_expected_density_t, uniform_expected_density_t,
                     structured_expected_density_t,
                     with_caps(banded_expected_density_t) if banded_ok
@@ -349,8 +453,10 @@ class TracedDensityStats:
         return jax.lax.switch(jnp.asarray(kind, jnp.int32), list(branches),
                               params, hist, tile_size * 1.0)
 
-    def prob_empty(self, kind, params, hist, tile_size):
-        return self._switch(self._pe, kind, params, hist, tile_size)
+    def prob_empty(self, kind, params, hist, tile_size,
+                   hyper=hyper_empty_t):
+        return _combine_pe(*self._switch(self._pe, kind, params, hist,
+                                         tile_size), hyper=hyper)
 
     def expected_density(self, kind, params, hist, tile_size):
         return self._switch(self._ed, kind, params, hist, tile_size)

@@ -144,7 +144,7 @@ def _pad_to(x: int, mult: int, floor: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
-def kernel_cell(M: int, K: int, N: int, *, bs: int = 64,
+def kernel_cell(M: int, K: int, N: int, *, bs: int = 128,
                 min_dim: int = 512) -> tuple[int, int, int]:
     """Pad a model shape up to a kernel- and timing-legal cell: K, N to
     block multiples >= min_dim, M to a multiple of 8 capped at 128 (the
@@ -155,7 +155,8 @@ def kernel_cell(M: int, K: int, N: int, *, bs: int = 64,
 
 
 def _measure_block_cell(Mk: int, Kk: int, Nk: int, *, density: float,
-                        bs: int, reps: int, seed: int = 0) -> dict:
+                        bs: int, reps: int, seed: int = 0,
+                        interpret: bool | None = None) -> dict:
     """Wall-clock the skip/gate kernels on one cell (interpret on CPU).
 
     Returns times for the full grid (dense), the skipped nonzero-block
@@ -176,14 +177,12 @@ def _measure_block_cell(Mk: int, Kk: int, Nk: int, *, density: float,
     full = np.ones_like(mask)
     kf, jf = block_indices(full)
     ks, js = block_indices(mask)
-    t_full = _timeit(lambda: skip_mm(a, w, kf, jf, bm=bs, bk=bs, bn=bs),
+    blk = dict(bm=bs, bk=bs, bn=bs, interpret=interpret)
+    t_full = _timeit(lambda: skip_mm(a, w, kf, jf, **blk), reps)
+    t_skip = _timeit(lambda: skip_mm(a, wm, ks, js, **blk), reps)
+    t_gate = _timeit(lambda: gated_mm(a, wm, jnp.asarray(mask), **blk),
                      reps)
-    t_skip = _timeit(lambda: skip_mm(a, wm, ks, js, bm=bs, bk=bs, bn=bs),
-                     reps)
-    t_gate = _timeit(
-        lambda: gated_mm(a, wm, jnp.asarray(mask), bm=bs, bk=bs, bn=bs),
-        reps)
-    got = skip_mm(a, wm, ks, js, bm=bs, bk=bs, bn=bs)
+    got = skip_mm(a, wm, ks, js, **blk)
     want = block_mm_ref(a, wm, jnp.asarray(mask), bk=bs, bn=bs)
     err = float(jnp.max(jnp.abs(got - want)))
     return {"t_full": t_full, "t_skip": t_skip, "t_gate": t_gate,
@@ -192,14 +191,15 @@ def _measure_block_cell(Mk: int, Kk: int, Nk: int, *, density: float,
 
 
 def _measure_nm_cell(Mk: int, Kk: int, Nk: int, *, n: int, m: int,
-                     reps: int, bs: int = 64, seed: int = 0) -> dict:
+                     reps: int, bs: int = 128, seed: int = 0,
+                     interpret: bool | None = None) -> dict:
     """Pack an N:M-pruned weight and measure what the advisor monetizes:
     the weight-bytes ratio of the real packed arrays, plus kernel
     correctness (and wall-clock, informational on CPU).
 
     ``bs`` block sizes are passed through to the kernel: cells are
-    padded to ``bs`` multiples, which need not divide the kernel's
-    default 128-wide blocks (``bs`` must be a multiple of ``m``)."""
+    padded to ``bs`` multiples (``bs`` must be a multiple of ``m``, and
+    of 128 for the TPU's kernel compiler)."""
     import jax.numpy as jnp
     from repro.kernels.nm_spmm.ops import nm_spmm
     from repro.sparsity.nm import nm_prune_dense, pack_nm, pack_offsets
@@ -213,9 +213,9 @@ def _measure_nm_cell(Mk: int, Kk: int, Nk: int, *, n: int, m: int,
     sparse_bytes = vals.nbytes + packed.nbytes
     dense_bytes = w.nbytes
     t_dense = _timeit(lambda: a @ w, reps)
-    t_nm = _timeit(lambda: nm_spmm(a, vals, idx, n=n, m=m, bk=bs, bn=bs),
-                   reps)
-    got = nm_spmm(a, vals, idx, n=n, m=m, bk=bs, bn=bs)
+    blk = dict(n=n, m=m, bk=bs, bn=bs, interpret=interpret)
+    t_nm = _timeit(lambda: nm_spmm(a, vals, idx, **blk), reps)
+    got = nm_spmm(a, vals, idx, **blk)
     want = a @ w_nm
     err = float(jnp.max(jnp.abs(got - want))
                 / max(1e-9, float(jnp.max(jnp.abs(want)))))
@@ -248,12 +248,16 @@ def _predict_block(shapes, *, density: float) -> dict:
 def validate_fleet(config_names=None, *, reduced: bool = True,
                    arms: Sequence[str] = ALL_ARMS,
                    density: float = 0.25, nm: tuple[int, int] = (2, 4),
-                   bs: int = 64, min_dim: int = 512, reps: int = 5,
+                   bs: int = 128, min_dim: int = 512, reps: int = 5,
                    max_cells_per_config: int = 2,
-                   seq_len: int = 256, batch: int = 8
+                   seq_len: int = 256, batch: int = 8,
+                   interpret: bool | None = None
                    ) -> list[AgreementRow]:
     """Run the agreement harness: advisor/model verdict signs vs
-    measured kernels on REDUCED-config shapes.
+    measured kernels on REDUCED-config shapes.  ``bs`` is the kernels'
+    block size (a multiple of 128: the TPU refuses narrower blocks);
+    ``interpret`` is passed to every kernel call (None: interpret on
+    the CPU only).
 
     Returns one row per (config, arm, cell); a row with
     ``agree=False`` is a modeling claim contradicted by a measurement
@@ -286,11 +290,12 @@ def validate_fleet(config_names=None, *, reduced: bool = True,
     if needs_block:
         for c in cells:
             block_meas[c] = _measure_block_cell(
-                *c, density=density, bs=bs, reps=reps)
+                *c, density=density, bs=bs, reps=reps, interpret=interpret)
     if "nm-traffic" in arms or "nm-correct" in arms:
         for c in cells:
             nm_meas[c] = _measure_nm_cell(*c, n=nm[0], m=nm[1],
-                                          reps=reps, bs=bs)
+                                          reps=reps, bs=bs,
+                                          interpret=interpret)
     pred = _predict_block(cells, density=density) if needs_block else {}
     cell_ix = {c: i for i, c in enumerate(cells)}
 

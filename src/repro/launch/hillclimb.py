@@ -66,6 +66,9 @@ def main(argv: list[str] | None = None) -> None:
     args = p.parse_args(argv)
 
     import jax
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     M, K, N = args.mkn
     dA, dB = args.densities
     wl = matmul(M, K, N, densities={"A": ("uniform", dA),

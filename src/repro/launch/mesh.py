@@ -7,6 +7,16 @@ XLA_FLAGS=--xla_force_host_platform_device_count BEFORE first jax use.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: tuple[int, ...], names: tuple[str, ...]):
+    """A mesh whose axes are all ``Auto``: the partitioner propagates
+    shardings from the ``PartitionSpec``s the layers declare.  (JAX's
+    ``make_mesh`` now defaults to ``Explicit`` axes, under which every
+    gather or contraction over a sharded axis must state its output
+    sharding; the layers are written for propagation.)"""
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names))
 
 
 def production_mesh_shape(*, multi_pod: bool = False
@@ -23,8 +33,8 @@ def production_mesh_shape(*, multi_pod: bool = False
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     axes_sizes = production_mesh_shape(multi_pod=multi_pod)
-    return jax.make_mesh(tuple(s for _, s in axes_sizes),
-                         tuple(a for a, _ in axes_sizes))
+    return auto_mesh(tuple(s for _, s in axes_sizes),
+                     tuple(a for a, _ in axes_sizes))
 
 
 def make_debug_mesh(devices: int | None = None):
@@ -35,7 +45,7 @@ def make_debug_mesh(devices: int | None = None):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

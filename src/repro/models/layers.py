@@ -14,6 +14,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from .config import MLAConfig, ModelConfig, MoEConfig
@@ -446,7 +447,15 @@ def init_embedding(cfg: ModelConfig, key):
 
 
 def embed(p, tokens, cfg: ModelConfig):
-    return p["tok"].astype(jnp.dtype(cfg.dtype))[tokens]
+    table = p["tok"].astype(jnp.dtype(cfg.dtype))
+    mesh = jax.typeof(table).sharding.mesh
+    if AxisType.Explicit not in mesh.axis_types:
+        return table[tokens]
+    # the table is sharded on rows; under Explicit axes the gather states
+    # its output sharding: rows follow the tokens, features replicated
+    spec = jax.typeof(tokens).sharding.spec
+    return table.at[tokens].get(
+        out_sharding=NamedSharding(mesh, P(*spec, None)))
 
 
 def lm_logits(p, x, cfg: ModelConfig):

@@ -16,29 +16,10 @@ from __future__ import annotations
 
 from functools import partial
 
-import inspect
-
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:                                     # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map_impl
-except ImportError:                      # older jax: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(*args, **kwargs):
-    """Version-compat wrapper: newer jax renamed ``check_rep`` to
-    ``check_vma``; translate whichever spelling the installed jax lacks."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map_impl(*args, **kwargs)
 
 
 def _quantize(g, key):

@@ -97,4 +97,5 @@ def elastic_mesh(prefer_model: int = 4):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    return auto_mesh((n // model, model), ("data", "model"))

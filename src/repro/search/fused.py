@@ -130,7 +130,7 @@ class FusedProgram:
                  strat: EvolutionStrategy, *, metric: str = "edp",
                  sgd_lr: float = 0.0, sgd_tau: float = 0.05,
                  archive_k: int = 0):
-        from jax.experimental import enable_x64
+        from jax import enable_x64
         with enable_x64():
             self._build(bm, enc, strat, metric=metric, sgd_lr=sgd_lr,
                         sgd_tau=sgd_tau, archive_k=archive_k)
@@ -463,7 +463,7 @@ class FusedProgram:
         survivor fold discards)."""
         import jax.numpy as jnp
         import jax.random as jrandom
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         if isinstance(key, (int, np.integer)):
             key = jrandom.PRNGKey(int(key))
@@ -473,7 +473,9 @@ class FusedProgram:
                 init_population(sub, self.enc, self.pop_size))
             pop0 = jnp.asarray(pop0, jnp.int32)
             fit0 = jnp.full((self.pop_size,), jnp.inf, jnp.float64)
-            carry = (key, pop0, fit0, pop0)
+            # pending gets its own buffer: the chunk donates the carry,
+            # and one buffer cannot be donated twice
+            carry = (key, pop0, fit0, jnp.array(pop0, copy=True))
             if self.archive_k:
                 # +inf placeholder rows: the dup mask ignores them
                 # (non-finite slot) and every real row sorts above them
@@ -491,7 +493,7 @@ class FusedProgram:
         migrants were evaluated on their home island and enter its
         archive there."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         key, pop, fit, pending, *buffer = carry
         g = self.enc.repair(np.asarray(genomes, np.int64))
@@ -513,7 +515,7 @@ class FusedProgram:
         (length, pop, genome) shape sighting is an ``engine.compile``
         span + ``compile_seconds``, later calls are ``engine.eval``."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         with enable_x64():
             fn = self._chunk_fn(length)

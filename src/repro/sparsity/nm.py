@@ -73,13 +73,16 @@ def pack_offsets(idx: jax.Array, m: int) -> jax.Array:
 
 
 def unpack_offsets(packed: jax.Array, m: int, rows: int) -> jax.Array:
-    """Inverse of pack_offsets -> int32 (rows, N)."""
+    """Inverse of pack_offsets -> int32 (rows, N).  Widens to int32
+    before the shifts and the reshape: the TPU kernel compiler (Mosaic)
+    cannot reshape uint8 vectors, and the nm_spmm kernel calls this."""
     bits = offsets_bits(m)
     per = 8 // bits
-    mask = jnp.uint8((1 << bits) - 1)
-    shifts = (jnp.arange(per, dtype=jnp.uint8) * bits)[None, :, None]
-    offs = ((packed[:, None, :] >> shifts) & mask)
-    return offs.reshape(rows, packed.shape[1]).astype(jnp.int32)
+    p = packed.astype(jnp.int32)
+    shifts = jax.lax.broadcasted_iota(
+        jnp.int32, (p.shape[0], per, p.shape[1]), 1) * bits
+    offs = (p[:, None, :] >> shifts) & ((1 << bits) - 1)
+    return offs.reshape(rows, p.shape[1])
 
 
 def unpack_nm_with(values: jax.Array, idx: jax.Array, n: int, m: int

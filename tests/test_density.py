@@ -91,7 +91,7 @@ def test_structured_prob_empty_monotone(m_block):
 def test_banded_batched_closed_forms_match_scalar():
     """The traceable banded expressions reproduce the scalar grid-count
     loops exactly (prob_empty / expected_density / max_nnz)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         for (rows, cols, w) in [(64, 64, 2), (64, 48, 5), (37, 53, 3),
                                 (16, 16, 0), (8, 64, 7)]:
@@ -110,7 +110,7 @@ def test_banded_batched_closed_forms_match_scalar():
 def test_banded_batched_traceable_under_vmap():
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     m = BandedModel(rows=32, cols=32, half_band=2)
     assert m.batched
     with enable_x64():

@@ -22,7 +22,7 @@ def _stats_for(models):
 def _check_parity(model, stats, tile_sizes, rel=1e-6):
     """Traced switch-dispatched stats == scalar oracle methods."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         params = jnp.asarray(model.params())
         hist = np.zeros((3, stats.caps.hist))
@@ -72,7 +72,7 @@ def test_actual_histogram_all_zero_and_dense_rows():
     _check_parity(row, stats, [1, 3, 8, 9, 64])
     # spot-check the documented scalar facts through the traced path
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         hist = np.zeros((3, stats.caps.hist))
         hist[:, :64] = row.hist_table()
@@ -102,7 +102,7 @@ def test_actual_histogram_table_semantics():
 def test_actual_batched_wrappers_traceable_under_vmap():
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     rng = np.random.default_rng(3)
     m = ActualDataModel(data=(rng.random(48) < 0.4).astype(float))
     assert m.batched
@@ -145,3 +145,58 @@ def test_caps_cover_and_pow2_rounding():
     assert merged == DensityCaps(coord=4, div=0, hist=8)
     # uniform-only workloads need no padding at all -> shared everywhere
     assert caps_for_models([UniformModel(1024, 0.5)]) == DensityCaps()
+
+
+# ----------------------------------------------------------------------
+# HyperBatch: every hypergeometric term of a program in one gammaln op
+# ----------------------------------------------------------------------
+def _hyper_program(models, tiles):
+    """A traced function asking prob_empty of each (model, tile) pair,
+    with the hypergeometric terms evaluated through ``hyper``."""
+    import jax.numpy as jnp
+    stats = _stats_for(models)
+    hist = jnp.zeros((3, max(1, stats.caps.hist)))
+
+    def body(ts, hyper):
+        return jnp.stack([
+            stats.prob_empty(m.kind_id, jnp.asarray(m.params()), hist,
+                             t, hyper)
+            for m in models for t in ts])
+    return body, jnp.asarray(tiles, jnp.float64)
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_hyper_batch_is_bit_identical_to_per_call_terms(batched):
+    """The two-trace batch gives, element by element, the bits of the
+    terms evaluated where they are asked for (and matches the oracle)."""
+    import jax
+    from jax import enable_x64
+
+    from repro.core.density import HyperBatch, hyper_empty_t
+    models = [UniformModel(tensor_size=4096, density=0.3),
+              StructuredModel(tensor_size=4096, n=2, m=8), DenseModel(64)]
+    tiles = [1.0, 2.0, 3.0, 7.0, 8.0, 64.0, 500.0]
+    with enable_x64():
+        body, ts = _hyper_program(models, tiles)
+        direct = jax.jit(lambda t: body(t, hyper_empty_t))(ts)
+        if batched:
+            got = jax.jit(lambda t: HyperBatch.run(
+                lambda h: body(t, h)))(ts)
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(direct))
+    want = [m.prob_empty(int(t)) for m in models for t in tiles]
+    np.testing.assert_allclose(np.asarray(direct), want, atol=1e-12)
+
+
+def test_hyper_batch_lowers_to_one_gammaln():
+    import jax
+    from jax import enable_x64
+
+    from repro.core.density import HyperBatch
+    models = [UniformModel(tensor_size=4096, density=0.3),
+              StructuredModel(tensor_size=4096, n=2, m=4)]
+    with enable_x64():
+        body, ts = _hyper_program(models, [1.0, 4.0, 9.0])
+        text = jax.jit(lambda t: HyperBatch.run(
+            lambda h: body(t, h))).lower(ts).as_text()
+    assert text.count("chlo.lgamma") == 1

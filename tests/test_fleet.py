@@ -354,7 +354,8 @@ def test_advise_backcompat():
 
 
 def test_kernel_cell_padding():
-    assert kernel_cell(8, 544, 300) == (8, 576, 512)
+    assert kernel_cell(8, 544, 300) == (8, 640, 512)
+    assert kernel_cell(8, 544, 300, bs=64) == (8, 576, 512)
     assert kernel_cell(1000, 512, 512) == (128, 512, 512)
     assert kernel_cell(3, 100, 100, bs=64, min_dim=128) == (8, 128, 128)
 

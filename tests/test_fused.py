@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import jax.random as jrandom
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import Sparseloop, compile_stats, matmul
 from repro.core.arch import (ArchParams, COMPUTE_FIELDS, STORAGE_FIELDS,
@@ -102,6 +102,30 @@ def test_fused_run_deterministic_and_validated():
     oracle = Sparseloop(DESIGN).evaluate(WL, a.best_nest)
     assert a.best.edp == pytest.approx(oracle.edp, rel=1e-9)
     assert a.log.evaluations == len(a.log.records) * 32
+
+
+@pytest.mark.parametrize("archive_k", [0, 4])
+def test_fused_carry_survives_donation(archive_k):
+    """Off the CPU each chunk donates its carry, and XLA refuses to
+    donate one buffer twice: every leaf of the initial carry must own
+    its buffer, and a chunk must run with the carry donated."""
+    import jax
+    enc = MapspaceEncoding(WL, 2, CONS)
+    pop = enc.random_population(jrandom.PRNGKey(0), 32)
+    bucket, _, _ = enc.decode_bucketed(pop)
+    bm = Sparseloop(DESIGN).bucketed_model(WL, bucket)
+    fp = get_fused_program(bm, enc, make_strategy("es"),
+                           archive_k=archive_k)
+    carry = fp.init_carry(0)
+    leaves = jax.tree.leaves(carry)
+    assert len({x.unsafe_buffer_pointer() for x in leaves}) == len(leaves)
+    with enable_x64():
+        run = jax.jit(fp._chunk_fn(2).__wrapped__, donate_argnums=(0,))
+        storage, comp = fp._base_params.leaves()
+        out, _ = run(carry, fp.bm._bind_params(None),
+                     jnp.asarray(storage, jnp.float64),
+                     jnp.asarray(comp, jnp.float64))
+    assert np.asarray(out[1]).shape == (32, enc.genome_size)
 
 
 def test_fused_chunking_invariant():

@@ -64,7 +64,8 @@ import jax
 from repro.configs import get_config
 from repro.launch.steps import ShapeSpec, input_specs, make_train_step
 from repro.launch.hloanalysis import analyze
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((4, 2), ("data", "model"))
 cfg = get_config("qwen3-4b", reduced=True)
 shape = ShapeSpec("tiny_train", "train", 64, 8)
 with mesh:
@@ -84,6 +85,32 @@ print("OK", costs.dot_flops)
                        text=True, cwd=REPO, env=env, timeout=480)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout
+
+
+@pytest.mark.parametrize("explicit", [False, True])
+def test_embed_gathers_a_row_sharded_table(explicit):
+    """The embedding table is sharded on its rows.  Under Explicit mesh
+    axes the gather must state its output sharding; under Auto axes the
+    partitioner propagates it.  Both give the unsharded lookup."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AxisType, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.models import layers as L
+    cfg = get_config("qwen2-0.5b", reduced=True)
+    p, spec = L.init_embedding(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.arange(12, dtype=jnp.int32).reshape(3, 4) % cfg.vocab_size
+    want = np.asarray(p["tok"].astype(jnp.dtype(cfg.dtype))[tokens])
+    kind = AxisType.Explicit if explicit else AxisType.Auto
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(kind,) * 2)
+    sharded = {k: jax.device_put(v, NamedSharding(mesh, spec[k]))
+               for k, v in p.items()}
+    tokens = jax.device_put(tokens, NamedSharding(mesh, P("data", None)))
+    with jax.set_mesh(mesh):
+        got = jax.jit(lambda q, t: L.embed(q, t, cfg))(sharded, tokens)
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_dryrun_sweep_results_have_no_errors():
