@@ -633,16 +633,13 @@ class _TracedNestModel:
         with _CACHE_LOCK:
             rec = _PROGRAM_CACHE.get(key)
             if rec is None:
-                with obs.span("engine.program", kind=self.kind,
-                              workload=self.workload.name):
-                    host = copy.copy(self)
-                    host.workload_params = None  # drop the heavy arrays
-                    host.arch_params = None
-                    host._prog = None
-                    rec = _ProgramRecord(
-                        kind=self.kind, single=host._vmapped,
-                        fn=jax.jit(jax.vmap(host._vmapped,
-                                            in_axes=(0, None))))
+                host = copy.copy(self)
+                host.workload_params = None  # drop the heavy arrays
+                host.arch_params = None
+                host._prog = None
+                rec = _ProgramRecord(
+                    kind=self.kind, single=host._vmapped,
+                    fn=jax.jit(jax.vmap(host._vmapped, in_axes=(0, None))))
                 compile_stats.record_program(self.kind)
                 if len(_PROGRAM_CACHE) >= _PROGRAM_CACHE_CAP:
                     _PROGRAM_CACHE.pop(next(iter(_PROGRAM_CACHE)))
@@ -725,15 +722,19 @@ class _TracedNestModel:
         while every later call at the shape is warm device time
         (``eval_seconds``, span ``engine.eval``).  The ``np.asarray``
         conversion blocks on the device result, so the measured interval
-        is host->device->host inclusive."""
+        is host->device->host inclusive; inside it, ``engine.dispatch``
+        spans the jitted call up to its return and ``engine.fetch`` the
+        conversions that wait on the device."""
         is_new = self._prog.note_compile(shape_key)
         name = "engine.compile" if is_new else "engine.eval"
         t0 = time.perf_counter()
         with obs.span(name, kind=self.kind,
                       workload=self.workload.name, candidates=n,
                       shape=shape_key):
-            out = fn(batch_args, wp)
-            out = {k: np.asarray(v) for k, v in out.items()}
+            with obs.span("engine.dispatch"):
+                out = fn(batch_args, wp)
+            with obs.span("engine.fetch"):
+                out = {k: np.asarray(v) for k, v in out.items()}
         dt = time.perf_counter() - t0
         if is_new:
             compile_stats.record_compile_seconds(dt)

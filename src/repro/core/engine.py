@@ -15,6 +15,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from .. import obs
 from .arch import Architecture
 from .dataflow import DenseTraffic, analyze_dataflow
 from .density import DensityModel, make_density_model
@@ -152,7 +153,11 @@ class Sparseloop:
         ``evaluate_designs``: lower the population once per group, then
         bind each entry of ``arch_params_list`` (None = the engine's
         own design) to the group's compiled program.  Returns one
-        result dict per entry, each aligned with the input order."""
+        result dict per entry, each aligned with the input order.
+
+        The ``engine.batch`` span covers the whole call; its self time
+        (less the ``engine.compile`` / ``engine.eval`` spans inside) is
+        the host's lowering and scatter."""
         from .batched import group_by_bucket, group_by_template, lower_nests
         nests = list(nests)
         outs: list[dict[str, np.ndarray]] = [{}
@@ -169,26 +174,31 @@ class Sparseloop:
                         dtype=bool if k == "valid" else np.float64)
                 out[k][idxs] = v
 
-        if not bucketed:
-            for template, idxs in group_by_template(nests).items():
-                model = self.batched_model(workload, template,
-                                           check_capacity, caps=caps)
-                bounds = np.stack([template.bounds_of(nests[i])
-                                   for i in idxs])
-                for out, ap in zip(outs, arch_params_list):
-                    scatter(out, idxs,
-                            model.evaluate(bounds, arch_params=ap))
-            return outs
+        with obs.span("engine.batch", candidates=len(nests)) as sp:
+            if not bucketed:
+                groups = group_by_template(nests)
+                sp.set(groups=len(groups))
+                for template, idxs in groups.items():
+                    model = self.batched_model(workload, template,
+                                               check_capacity, caps=caps)
+                    bounds = np.stack([template.bounds_of(nests[i])
+                                       for i in idxs])
+                    for out, ap in zip(outs, arch_params_list):
+                        scatter(out, idxs,
+                                model.evaluate(bounds, arch_params=ap))
+                return outs
 
-        ranks = tuple(workload.rank_bounds)
-        for bucket, idxs in group_by_bucket(nests, ranks).items():
-            model = self.bucketed_model(workload, bucket, check_capacity,
-                                        caps=caps)
-            bounds, ids, order = lower_nests(bucket, nests, idxs)
-            for out, ap in zip(outs, arch_params_list):
-                scatter(out, order,
-                        model.evaluate(bounds, ids, arch_params=ap))
-        return outs
+            ranks = tuple(workload.rank_bounds)
+            groups = group_by_bucket(nests, ranks)
+            sp.set(groups=len(groups))
+            for bucket, idxs in groups.items():
+                model = self.bucketed_model(workload, bucket,
+                                            check_capacity, caps=caps)
+                bounds, ids, order = lower_nests(bucket, nests, idxs)
+                for out, ap in zip(outs, arch_params_list):
+                    scatter(out, order,
+                            model.evaluate(bounds, ids, arch_params=ap))
+            return outs
 
     def evaluate_network(self, workloads: Sequence[Workload],
                          nests_per_workload,

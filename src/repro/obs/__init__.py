@@ -14,13 +14,12 @@ See :mod:`repro.obs.trace` (tracer, ``REPRO_TRACE`` switch),
 """
 from . import metrics
 from .export import (chrome_trace_events, validate_chrome_trace,
-                     validate_chrome_trace_file, write_chrome_trace)
+                     write_chrome_trace)
 from .trace import (TRACE_ENV, JsonlSink, Span, Tracer, configure_from_env,
                     disable, enable, enabled, span, tracer)
 
 __all__ = [
     "TRACE_ENV", "JsonlSink", "Span", "Tracer", "chrome_trace_events",
     "configure_from_env", "disable", "enable", "enabled", "metrics",
-    "span", "tracer", "validate_chrome_trace",
-    "validate_chrome_trace_file", "write_chrome_trace",
+    "span", "tracer", "validate_chrome_trace", "write_chrome_trace",
 ]

@@ -27,8 +27,9 @@ _EPS_US = 0.01
 def chrome_trace_events(spans: list[Span],
                         metrics_snapshot: dict | None = None
                         ) -> list[dict]:
-    """Spans -> Chrome trace events ("X" complete events, µs timebase),
-    plus thread-name metadata and an optional final metrics snapshot."""
+    """Spans -> Chrome trace events ("X" complete events, µs timebase,
+    each with its span's ``sid`` and ``parent``), plus thread-name
+    metadata and an optional final metrics snapshot."""
     tid_of: dict[int, int] = {}
     for s in spans:
         tid_of.setdefault(s.tid, len(tid_of))
@@ -39,6 +40,7 @@ def chrome_trace_events(spans: list[Span],
             "ts": round(s.t_start * 1e6, 3),
             "dur": round(max(0.0, s.dur) * 1e6, 3),
             "pid": 0, "tid": tid_of[s.tid],
+            "sid": s.sid, "parent": s.parent,
             "args": jsonable(s.attrs),
         })
     for raw, tid in tid_of.items():
@@ -122,12 +124,3 @@ def validate_chrome_trace(obj: dict) -> list[str]:
                 continue
             stack.append((ts, end, name))
     return errors
-
-
-def validate_chrome_trace_file(path: str) -> list[str]:
-    try:
-        with open(path) as f:
-            obj = json.load(f)
-    except (OSError, json.JSONDecodeError) as e:
-        return [f"cannot read {path}: {e}"]
-    return validate_chrome_trace(obj)

@@ -11,9 +11,22 @@ on the monotonic clock with arbitrary key/value attributes::
 
 Spans nest: each thread keeps its own span stack (``threading.local``),
 so concurrent serving/search threads never interleave their depths.
+Every span gets an id (``sid``) and the id of the span that encloses it
+on its thread (``parent``), so a span's self time — its duration less
+its children's — follows from the links, not from guessing by interval.
 Durations come from ``time.perf_counter()`` relative to the tracer's
 epoch, so all spans of a process share one timebase and the Chrome-trace
 export (:mod:`repro.obs.export`) is directly Perfetto-loadable.
+
+Profiler clock
+--------------
+Once ``jax`` is imported, every span of an enabled tracer also enters a
+``jax.profiler.TraceAnnotation`` of the same name (the name only; the
+attributes stay in the span record).  Inside a running
+``jax.profiler`` trace the spans then sit on the host plane of the
+``.xplane.pb``, on the device trace's clock, so device idle time can be
+named by the program span around it.  Outside a profiler trace an
+annotation costs about a microsecond.
 
 Sinks
 -----
@@ -29,7 +42,8 @@ Disabled-by-default switch
 Tracing is OFF unless enabled in code or via ``REPRO_TRACE``:
 
 * unset / ``0`` / ``off`` — disabled; ``span()`` returns a shared no-op
-  context manager (no allocation, no clock read — the near-no-op path);
+  context manager (no allocation, no clock read, no annotation — the
+  near-no-op path);
 * ``1`` / ``mem`` — in-memory tracing;
 * ``<path>.jsonl`` — in-memory + JSONL event log at that path;
 * ``<path>.json`` — in-memory + Chrome trace written there at exit.
@@ -42,8 +56,10 @@ from __future__ import annotations
 import atexit
 import contextlib
 import dataclasses
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 import warnings
@@ -84,6 +100,8 @@ class Span:
     tid: int                # OS thread ident
     depth: int              # nesting depth on its thread's span stack
     attrs: dict
+    sid: int = 0            # id, unique within its tracer
+    parent: int | None = None   # sid of the enclosing span on its thread
 
     @property
     def dur(self) -> float:
@@ -138,8 +156,9 @@ class JsonlSink:
     def emit(self, span: Span) -> None:
         line = json.dumps(
             {"name": span.name, "ts": span.t_start, "dur": span.dur,
-             "tid": span.tid, "depth": span.depth,
-             "attrs": jsonable(span.attrs)}, sort_keys=True)
+             "tid": span.tid, "depth": span.depth, "sid": span.sid,
+             "parent": span.parent, "attrs": jsonable(span.attrs)},
+            sort_keys=True)
         with self._lock:
             self._f.write(line + "\n")
             self._f.flush()
@@ -159,8 +178,10 @@ class Tracer:
         self.sinks = list(sinks)
         self._lock = threading.Lock()
         self._local = threading.local()
+        self._sids = itertools.count(1)
 
     def _stack(self) -> list:
+        """This thread's open span ids, outermost first."""
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
@@ -168,23 +189,29 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
+        jax = sys.modules.get("jax")
+        note = (jax.profiler.TraceAnnotation(name)
+                if jax is not None else contextlib.nullcontext())
         stack = self._stack()
         depth = len(stack)
-        stack.append(name)
+        parent = stack[-1] if stack else None
+        sid = next(self._sids)
+        stack.append(sid)
         handle = _SpanHandle(attrs)
-        t0 = time.perf_counter() - self.epoch
-        try:
-            yield handle
-        finally:
-            t1 = time.perf_counter() - self.epoch
-            stack.pop()
-            rec = Span(name=name, t_start=t0, t_end=t1,
-                       tid=threading.get_ident(), depth=depth,
-                       attrs=handle.attrs)
-            with self._lock:
-                self.spans.append(rec)
-            for sink in self.sinks:
-                sink.emit(rec)
+        with note:
+            t0 = time.perf_counter() - self.epoch
+            try:
+                yield handle
+            finally:
+                t1 = time.perf_counter() - self.epoch
+                stack.pop()
+                rec = Span(name=name, t_start=t0, t_end=t1,
+                           tid=threading.get_ident(), depth=depth,
+                           attrs=handle.attrs, sid=sid, parent=parent)
+                with self._lock:
+                    self.spans.append(rec)
+                for sink in self.sinks:
+                    sink.emit(rec)
 
     def find(self, name: str) -> list[Span]:
         with self._lock:

@@ -513,7 +513,9 @@ class FusedProgram:
         arrays with a leading generation axis.  Compile/eval seconds are
         attributed exactly like the batched evaluators: the first
         (length, pop, genome) shape sighting is an ``engine.compile``
-        span + ``compile_seconds``, later calls are ``engine.eval``."""
+        span + ``compile_seconds``, later calls are ``engine.eval``; in
+        either, ``engine.dispatch`` spans the jitted call and
+        ``engine.fetch`` the host conversions that wait on it."""
         import jax.numpy as jnp
         from jax import enable_x64
 
@@ -533,18 +535,21 @@ class FusedProgram:
                           workload=self.bm.workload.name,
                           candidates=length * self.pop_size,
                           shape=shape_key):
-                carry, ys = fn(carry, wp, base_storage, base_comp)
-                if self.archive_k:
-                    ys = {k: np.asarray(v)
-                          for k, v in zip(YS_TOPK_FIELDS, ys)}
-                    # ONE K-row host crossing per chunk: the cumulative
-                    # top-K buffer snapshot (the carry persists, so this
-                    # is global-so-far, not per-chunk)
-                    ys["archive_fitness"] = np.asarray(carry[4])
-                    ys["archive_genomes"] = np.asarray(carry[5])
-                else:
-                    ys = {k: np.asarray(v)
-                          for k, v in zip(YS_FIELDS, ys)}
+                with obs.span("engine.dispatch"):
+                    carry, ys = fn(carry, wp, base_storage, base_comp)
+                with obs.span("engine.fetch"):
+                    if self.archive_k:
+                        ys = {k: np.asarray(v)
+                              for k, v in zip(YS_TOPK_FIELDS, ys)}
+                        # ONE K-row host crossing per chunk: the
+                        # cumulative top-K buffer snapshot (the carry
+                        # persists, so this is global-so-far, not
+                        # per-chunk)
+                        ys["archive_fitness"] = np.asarray(carry[4])
+                        ys["archive_genomes"] = np.asarray(carry[5])
+                    else:
+                        ys = {k: np.asarray(v)
+                              for k, v in zip(YS_FIELDS, ys)}
             dt = time.perf_counter() - t0
             if is_new:
                 compile_stats.record_compile_seconds(dt)

@@ -54,7 +54,9 @@ class SearchLog:
     records: list[GenerationRecord] = dataclasses.field(
         default_factory=list)
     #: run-level wall-clock attribution (wall_s / compile_s / eval_s /
-    #: compiles), filled by ``run_search`` from ``compile_stats``
+    #: compiles, filled by ``run_search`` from ``compile_stats``; the
+    #: host seconds before the loop, prepare_s, and of the oracle walk,
+    #: validate_s; a fused run's per-chunk rows, chunks)
     timing: dict = dataclasses.field(default_factory=dict)
 
     def append(self, rec: GenerationRecord) -> None:

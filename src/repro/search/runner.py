@@ -426,20 +426,25 @@ def _run_fused(evaluate: PopulationEvaluator, enc, strat, key,
     compiled ``lax.scan`` dispatch (``search.fused``); the host only
     absorbs each chunk's per-generation outputs into the archive.
     Returns the same state as :func:`_run_host` plus the chunk-timing
-    rows for ``log.timing``."""
+    rows for ``log.timing`` and the seconds spent preparing the scan
+    (program lookup and initial carry)."""
     from .fused import ChunkAbsorber, get_fused_program
 
-    bm = evaluate.model.bucketed_model(
-        evaluate.workload, enc.bucket, check_capacity=check_capacity)
-    # device-resident archive: the scan carries a top-K (fitness,
-    # genome) buffer and emits per-generation scalars, so the host
-    # fold ingests K rows per chunk instead of pop_size per generation
-    fp = get_fused_program(bm, enc, strat, metric=metric,
-                           sgd_lr=sgd_lr, sgd_tau=sgd_tau,
-                           archive_k=ARCHIVE_SIZE)
-    carry = fp.init_carry(key)
-    absorber = ChunkAbsorber(metric, ARCHIVE_SIZE,
-                             pop_size=strat.pop_size)
+    t_prep0 = time.perf_counter()
+    with obs.span("search.prepare", fused=True):
+        bm = evaluate.model.bucketed_model(
+            evaluate.workload, enc.bucket, check_capacity=check_capacity)
+        # device-resident archive: the scan carries a top-K (fitness,
+        # genome) buffer and emits per-generation scalars, so the host
+        # fold ingests K rows per chunk instead of pop_size per
+        # generation
+        fp = get_fused_program(bm, enc, strat, metric=metric,
+                               sgd_lr=sgd_lr, sgd_tau=sgd_tau,
+                               archive_k=ARCHIVE_SIZE)
+        carry = fp.init_carry(key)
+        absorber = ChunkAbsorber(metric, ARCHIVE_SIZE,
+                                 pop_size=strat.pop_size)
+    prepare_s = time.perf_counter() - t_prep0
     chunks: list[dict] = []
     done = 0
     while done < generations:
@@ -452,14 +457,15 @@ def _run_fused(evaluate: PopulationEvaluator, enc, strat, key,
                     lambda carry=carry, c=c: fp.invoke_chunk(carry, c))
             else:
                 carry, ys = fp.invoke_chunk(carry, c)
-            absorber.absorb(ys, log)
+            with obs.span("search.absorb"):
+                absorber.absorb(ys, log)
             sp.set(evaluations=absorber.n_eval,
                    best_fitness=absorber.best["fitness"])
         chunks.append({"start": done, "generations": c,
                        "wall_s": time.perf_counter() - t0})
         done += c
     return (absorber.archive_fit, absorber.archive_gen,
-            absorber.n_eval, absorber.n_valid, chunks)
+            absorber.n_eval, absorber.n_valid, chunks, prepare_s)
 
 
 def run_search(design, workload: Workload,
@@ -534,111 +540,133 @@ def run_search(design, workload: Workload,
     genes inside the scan body (log-space Lamarckian nudge against the
     smooth capacity-surrogate loss, temperature ``sgd_tau``).
     """
-    import jax.random as jrandom
+    t_entry = time.perf_counter()
+    with obs.span("search.run", metric=metric,
+                  workload=workload.name) as run_sp:
+        with obs.span("search.prepare"):
+            import jax.random as jrandom
 
-    if metric not in METRICS:
-        raise ValueError(f"metric must be one of {METRICS}, got {metric!r}")
-    cons = cons or MapspaceConstraints()
-    strat = make_strategy(strategy, **strategy_options)
-    if topology_space is not None:
-        if design is not None:
-            raise ValueError(
-                "topology co-search decodes designs from the "
-                "TopologySpace genome; pass design=None (the base "
-                "levels live in the space's slots)")
-        enc: MapspaceEncoding = TopologyCoSearchEncoding(
-            workload, cons, topology_space, design_space)
-        design = enc.representative_design()
-    elif design_space is not None:
-        enc = CoSearchEncoding(
-            workload, design.arch.num_levels, cons, design_space, design)
-    else:
-        enc = MapspaceEncoding(workload, design.arch.num_levels, cons)
-    if service is not None:
-        mesh = None        # the service owns the devices
-    elif mesh == "auto":
-        mesh = population_mesh()
-    config = config or SearchConfig()
-    if batch_threshold is not None:
-        config = dataclasses.replace(config,
-                                     batch_threshold=batch_threshold)
-    evaluate = PopulationEvaluator(design, workload, enc, mesh=mesh,
-                                   check_capacity=check_capacity,
-                                   config=config, service=service)
+            if metric not in METRICS:
+                raise ValueError(
+                    f"metric must be one of {METRICS}, got {metric!r}")
+            cons = cons or MapspaceConstraints()
+            strat = make_strategy(strategy, **strategy_options)
+            if topology_space is not None:
+                if design is not None:
+                    raise ValueError(
+                        "topology co-search decodes designs from the "
+                        "TopologySpace genome; pass design=None (the base "
+                        "levels live in the space's slots)")
+                enc: MapspaceEncoding = TopologyCoSearchEncoding(
+                    workload, cons, topology_space, design_space)
+                design = enc.representative_design()
+            elif design_space is not None:
+                enc = CoSearchEncoding(workload, design.arch.num_levels,
+                                       cons, design_space, design)
+            else:
+                enc = MapspaceEncoding(workload, design.arch.num_levels, cons)
+            if service is not None:
+                mesh = None        # the service owns the devices
+            elif mesh == "auto":
+                mesh = population_mesh()
+            config = config or SearchConfig()
+            if batch_threshold is not None:
+                config = dataclasses.replace(config,
+                                             batch_threshold=batch_threshold)
+            evaluate = PopulationEvaluator(design, workload, enc, mesh=mesh,
+                                           check_capacity=check_capacity,
+                                           config=config, service=service)
 
-    seed = key if isinstance(key, (int, np.integer)) else None
-    if seed is not None:
-        key = jrandom.PRNGKey(int(seed))
-    if generations is None:
-        # honour cons.budget as a hard cap: shrink the population when
-        # it exceeds the whole budget, then spend it in full generations
-        if strat.pop_size > cons.budget > 0:
-            strat = make_strategy(strat, pop_size=cons.budget)
-        generations = max(1, cons.budget // max(1, strat.pop_size))
+            seed = key if isinstance(key, (int, np.integer)) else None
+            if seed is not None:
+                key = jrandom.PRNGKey(int(seed))
+            if generations is None:
+                # honour cons.budget as a hard cap: shrink the population
+                # when it exceeds the whole budget, then spend it in full
+                # generations
+                if strat.pop_size > cons.budget > 0:
+                    strat = make_strategy(strat, pop_size=cons.budget)
+                generations = max(1, cons.budget // max(1, strat.pop_size))
 
-    from .fused import fused_supported
-    want_fused = config.fused if fused is None else fused
-    use_fused = (want_fused and isinstance(strat, EvolutionStrategy)
-                 and evaluate.batched and config.bucketed
-                 and enc.genome_size > 0
-                 and strat.pop_size >= max(1, config.batch_threshold)
-                 and fused_supported(enc))
-    if fused and not use_fused:
-        warnings.warn(
-            "fused=True requested but this run is not fused-eligible "
-            "(needs an EvolutionStrategy on the bucketed batched path "
-            "with traced design knobs); using the host loop",
-            stacklevel=2)
+            from .fused import fused_supported
+            want_fused = config.fused if fused is None else fused
+            use_fused = (want_fused and isinstance(strat, EvolutionStrategy)
+                         and evaluate.batched and config.bucketed
+                         and enc.genome_size > 0
+                         and strat.pop_size >= max(1, config.batch_threshold)
+                         and fused_supported(enc))
+            if fused and not use_fused:
+                warnings.warn(
+                    "fused=True requested but this run is not fused-eligible "
+                    "(needs an EvolutionStrategy on the bucketed batched path "
+                    "with traced design knobs); using the host loop",
+                    stacklevel=2)
 
-    log = log_to or SearchLog(strategy=strat.name, metric=metric,
-                              workload=workload.name,
-                              design=design.name or design.arch.name,
-                              seed=None if seed is None else int(seed))
+            log = log_to or SearchLog(strategy=strat.name, metric=metric,
+                                      workload=workload.name,
+                                      design=design.name or design.arch.name,
+                                      seed=None if seed is None else int(seed))
 
-    t_run0 = time.perf_counter()
-    with compile_stats.track() as st, \
-            obs.span("search.run", strategy=strat.name, metric=metric,
-                     workload=workload.name, generations=generations,
-                     pop_size=strat.pop_size, fused=use_fused):
+        prepare_s = time.perf_counter() - t_entry
+        run_sp.set(strategy=strat.name, generations=generations,
+                   pop_size=strat.pop_size, fused=use_fused)
+
+        t_run0 = time.perf_counter()
+        with compile_stats.track() as st:
+            if use_fused:
+                archive_fit, archive_gen, n_eval, n_valid, chunks, fused_s = \
+                    _run_fused(evaluate, enc, strat, key, generations,
+                               metric, check_capacity, config, service,
+                               sgd_lr, sgd_tau, log)
+                prepare_s += fused_s
+            else:
+                archive_fit, archive_gen, n_eval, n_valid = _run_host(
+                    evaluate, enc, strat, key, generations, metric, log)
+        # run-level wall-clock attribution: where the search's seconds went
+        # (compile vs warm-eval, from compile_stats' seconds counters)
+        log.timing = {
+            "wall_s": time.perf_counter() - t_run0,
+            "compile_s": st.compile_seconds,
+            "eval_s": st.eval_seconds,
+            "compiles": st.compiles,
+        }
         if use_fused:
-            archive_fit, archive_gen, n_eval, n_valid, chunks = \
-                _run_fused(evaluate, enc, strat, key, generations,
-                           metric, check_capacity, config, service,
-                           sgd_lr, sgd_tau, log)
-        else:
-            archive_fit, archive_gen, n_eval, n_valid = _run_host(
-                evaluate, enc, strat, key, generations, metric, log)
-    # run-level wall-clock attribution: where the search's seconds went
-    # (compile vs warm-eval, from compile_stats' seconds counters)
-    log.timing = {
-        "wall_s": time.perf_counter() - t_run0,
-        "compile_s": st.compile_seconds,
-        "eval_s": st.eval_seconds,
-        "compiles": st.compiles,
-    }
-    if use_fused:
-        # honest chunk-level attribution: per-generation wall_time_s is
-        # None inside a scan, the measurable unit is the chunk dispatch
-        log.timing["fused"] = True
-        log.timing["chunks"] = chunks
+            # honest chunk-level attribution: per-generation wall_time_s is
+            # None inside a scan, the measurable unit is the chunk dispatch
+            log.timing["fused"] = True
+            log.timing["chunks"] = chunks
 
-    # scalar-oracle validation of the winner (best-first archive walk);
-    # co-search candidates validate under THEIR OWN design, and the
-    # winner's design rides out on the result
-    order = np.argsort(archive_fit, kind="stable")[:ARCHIVE_SIZE]
-    model_at = None
-    if design_space is not None or topology_space is not None:
-        # reuse the evaluator's per-design oracle cache: archive rows
-        # repeat a handful of (topology, design) points, and each
-        # candidate validates under its OWN decoded Design
-        model_at = (lambda i:
-                    evaluate._scalar_model(archive_gen[order[i]]))
-    result = _validated_result(
-        evaluate.model, workload,
-        lambda i: enc.nest_of(archive_gen[order[i]]),
-        edp=np.asarray([archive_fit[k] for k in order]),
-        valid=np.ones(len(order), dtype=bool),
-        n_eval=n_eval, check_capacity=check_capacity, model_at=model_at)
-    result.valid = n_valid
-    result.log = log
-    return result
+        # scalar-oracle validation of the winner (best-first archive walk);
+        # co-search candidates validate under THEIR OWN design, and the
+        # winner's design rides out on the result
+        order = np.argsort(archive_fit, kind="stable")[:ARCHIVE_SIZE]
+        walked = 0
+
+        def nest_at(i):
+            nonlocal walked
+            walked += 1
+            return enc.nest_of(archive_gen[order[i]])
+
+        model_at = None
+        if design_space is not None or topology_space is not None:
+            # reuse the evaluator's per-design oracle cache: archive rows
+            # repeat a handful of (topology, design) points, and each
+            # candidate validates under its OWN decoded Design
+            model_at = (lambda i:
+                        evaluate._scalar_model(archive_gen[order[i]]))
+        t_val0 = time.perf_counter()
+        with obs.span("search.validate") as val_sp:
+            result = _validated_result(
+                evaluate.model, workload, nest_at,
+                edp=np.asarray([archive_fit[k] for k in order]),
+                valid=np.ones(len(order), dtype=bool),
+                n_eval=n_eval, check_capacity=check_capacity,
+                model_at=model_at)
+            val_sp.set(walked=walked)
+        # host seconds around the loop: entry set-up up to the first chunk
+        # (or generation), and the oracle walk
+        log.timing["prepare_s"] = prepare_s
+        log.timing["validate_s"] = time.perf_counter() - t_val0
+        result.valid = n_valid
+        result.log = log
+        return result

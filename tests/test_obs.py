@@ -103,6 +103,37 @@ def test_thread_local_span_stacks_do_not_interleave():
     assert len({s.tid for s in tr.spans}) == 2
 
 
+def test_sid_and_parent_round_trip_through_sinks(tmp_path):
+    jl, ch = tmp_path / "events.jsonl", tmp_path / "trace.json"
+    tr = obs.enable(jsonl=str(jl), chrome=str(ch))
+    with obs.span("outer"):
+        with obs.span("inner"):
+            with obs.span("leaf"):
+                pass
+        with obs.span("inner"):
+            pass
+    spans = list(tr.spans)
+    obs.disable()
+    (outer,) = [s for s in spans if s.name == "outer"]
+    inners = [s for s in spans if s.name == "inner"]
+    (leaf,) = [s for s in spans if s.name == "leaf"]
+    assert len({s.sid for s in spans}) == 4
+    assert outer.parent is None
+    assert [s.parent for s in inners] == [outer.sid, outer.sid]
+    assert leaf.parent == inners[0].sid
+    # self time from the links: a span less its direct children
+    own = outer.dur - sum(s.dur for s in spans if s.parent == outer.sid)
+    assert 0 <= own <= outer.dur
+    want = sorted((s.name, s.sid, s.parent) for s in spans)
+    lines = [json.loads(ln) for ln in jl.read_text().splitlines()]
+    assert sorted((ln["name"], ln["sid"], ln["parent"])
+                  for ln in lines) == want
+    obj = json.loads(ch.read_text())
+    assert validate_chrome_trace(obj) == []
+    assert sorted((e["name"], e["sid"], e["parent"])
+                  for e in obj["traceEvents"] if e["ph"] == "X") == want
+
+
 # ----------------------------------------------------------------------
 # REPRO_TRACE switch + sinks
 # ----------------------------------------------------------------------
@@ -399,3 +430,108 @@ def test_searchlog_save_is_atomic_replace(tmp_path, monkeypatch):
     with pytest.raises(OSError):
         log2.save(path)
     assert path.read_text() == good   # old content intact
+
+
+# ----------------------------------------------------------------------
+# the profiler's clock: spans as TraceAnnotations in a jax.profiler trace
+# ----------------------------------------------------------------------
+@pytest.fixture
+def fresh_programs():
+    """Programs compiled here are dropped afterwards: later tests in the
+    process count the compiles they make."""
+    from repro.core.batched import clear_caches
+    clear_caches()
+    yield
+    clear_caches()
+
+
+def _small_search_and_sweep():
+    """A small fused search, then the winner through the network path
+    (one single-candidate call, as the fleet sweep makes)."""
+    from repro.core import Sparseloop, matmul
+    from repro.core.mapper import MapspaceConstraints
+    from repro.core.presets import coordinate_list_design, two_level_arch
+    from repro.search import run_search
+    wl = matmul(32, 32, 32, densities={"A": ("uniform", 0.3),
+                                       "B": ("uniform", 0.3)})
+    design = coordinate_list_design(two_level_arch(buffer_kwords=8))
+    cons = MapspaceConstraints(budget=96, seed=0, spatial={1: {"n": 4}})
+    res = run_search(design, wl, cons, strategy="es", key=3, mesh=None,
+                     fused=True)
+    out = Sparseloop(design).evaluate_network([wl], [[res.best_nest]])
+    return res, out
+
+
+def _inside(events, child: str, parent: str) -> list[bool]:
+    """For each ``child`` event, whether some ``parent`` event on the
+    same clock contains it."""
+    outer = [(s, e) for n, s, e in events if n == parent]
+    return [any(ps <= s and e <= pe for ps, pe in outer)
+            for n, s, e in events if n == child]
+
+
+def test_spans_nest_on_the_profiler_clock(tmp_path, fresh_programs):
+    import jax
+    from jax.profiler import ProfileData
+    _small_search_and_sweep()            # compiles stay out of the trace
+    tr = obs.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res, out = _small_search_and_sweep()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    prog = ("search.", "engine.")
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith(prog)]
+    names = [n for n, _, _ in events]
+    # every span of the tracer is on the profiler's host plane, once
+    for name in {s.name for s in tr.spans}:
+        assert names.count(name) == len(tr.find(name)), name
+    for child in ("search.prepare", "search.chunk", "search.absorb",
+                  "search.validate"):
+        assert names.count(child) >= 1
+        assert all(_inside(events, child, "search.run")), child
+    assert all(_inside(events, "search.absorb", "search.chunk"))
+    for child in ("engine.dispatch", "engine.fetch"):
+        assert all(_inside(events, child, "engine.eval")), child
+    # the network call: dispatch and fetch inside engine.eval inside
+    # engine.batch
+    assert any(_inside(events, "engine.eval", "engine.batch"))
+    (batch,) = [(s, e) for n, s, e in events if n == "engine.batch"]
+    assert {n for n, s, e in events if batch[0] <= s and e <= batch[1]} \
+        == {"engine.batch", "engine.eval", "engine.dispatch",
+            "engine.fetch"}
+    # one clock: the search's phases follow each other in order
+    first = {n: min(s for m, s, _ in events if m == n)
+             for n in ("search.prepare", "search.chunk", "search.validate")}
+    assert first["search.prepare"] < first["search.chunk"] \
+        < first["search.validate"]
+    # the attributes stay in the obs record
+    (val,) = tr.find("search.validate")
+    assert val.attrs["walked"] >= 1
+    (bsp,) = tr.find("engine.batch")
+    assert bsp.attrs == {"candidates": 1, "groups": 1}
+    assert res.log.timing["prepare_s"] > 0
+    assert res.log.timing["validate_s"] > 0
+    assert np.isfinite(out[0]["edp"]).all()
+
+
+def test_disabled_tracer_builds_no_annotation(monkeypatch, fresh_programs):
+    import jax
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("TraceAnnotation built with tracing off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    obs.enable()                         # the patch is what a span uses
+    with pytest.raises(AssertionError):
+        with obs.span("x"):
+            pass
+    assert obs.tracer().spans == []
+    obs.disable()
+    res, _ = _small_search_and_sweep()   # off: no annotation anywhere
+    assert res.best is not None
