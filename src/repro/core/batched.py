@@ -80,6 +80,12 @@ independent of the number of design points
 workload params across devices and shards the arch rows with their
 candidates.
 
+Rows of different workloads (one candidate per workload) share a call
+too: :meth:`BucketedModel.evaluate_rows` vmaps the same traced step
+over stacked per-row workload params (:func:`stack_workload_params`) as
+well, ``ROW_BLOCK`` rows at a time, so a fleet sweep of hundreds of
+shapes makes a handful of program calls instead of one per shape.
+
 ``BatchedModel.evaluate`` matches scalar ``Sparseloop.evaluate`` to
 float64 round-off (tests/test_batched.py pins <=1e-6 relative, and
 tests/test_bucketed.py pins the padded-bucket path against both); the
@@ -164,7 +170,9 @@ class WorkloadParams:
     traced data: any tensor may be actual-data in some layer of the
     sweep, so every tensor needs a row for the program to stay
     layer-agnostic.  The device copy is made once per params object
-    (:meth:`device_leaves`)."""
+    (:meth:`device_leaves`).  :func:`stack_workload_params` stacks the
+    params of several workloads along a leading row axis, for the row
+    path (:meth:`BucketedModel.evaluate_rows`)."""
 
     rank_bounds: np.ndarray
     model_ids: np.ndarray
@@ -233,6 +241,32 @@ def pack_workload_params(workload: Workload,
     return WorkloadParams(rank_bounds=rank_bounds, model_ids=model_ids,
                           density_params=density_params, hist=hist,
                           caps=caps, structure=workload_structure(workload))
+
+
+def stack_workload_params(params) -> WorkloadParams:
+    """Stack per-workload :class:`WorkloadParams` along a leading row
+    axis: row i of every leaf is ``params[i]``'s.  This is where rows of
+    different workloads meet on one candidate axis, so every row must
+    share the caps and the structure the program is keyed by; a row
+    that does not raises."""
+    params = list(params)
+    if not params:
+        raise ValueError("no workload params to stack")
+    first = params[0]
+    for i, p in enumerate(params):
+        if p.caps != first.caps:
+            raise ValueError(
+                f"row {i} was packed with caps {p.caps}, row 0 with "
+                f"{first.caps}; pack every row with common_caps of the "
+                f"rows")
+        if p.structure != first.structure:
+            raise ValueError(
+                f"row {i} was packed for a different workload structure "
+                f"(rank names / projections / output) than row 0 — its "
+                f"metrics would be silently wrong")
+    leaves = [np.stack(xs) for xs in zip(*(p.leaves() for p in params))]
+    return WorkloadParams(*leaves, caps=first.caps,
+                          structure=first.structure)
 
 
 def common_caps(workloads) -> DensityCaps:
@@ -510,6 +544,18 @@ class _ProgramRecord:
     #: ``BucketedModel.evaluate_with_arch_grad`` and shared exactly like
     #: ``fn`` (the closure only reads structural attributes)
     grad_fns: dict = dataclasses.field(default_factory=dict)
+    #: jit(vmap(single, (0, 0))): the same traced step with the workload
+    #: params vmapped too, one workload per row — built on first use by
+    #: :meth:`row_program`
+    rows_fn: object = None
+
+    def row_program(self):
+        """The row variant of ``fn`` (``BucketedModel.evaluate_rows``)."""
+        with _CACHE_LOCK:
+            if self.rows_fn is None:
+                self.rows_fn = jax.jit(
+                    jax.vmap(self.single, in_axes=(0, 0)))
+            return self.rows_fn
 
     def note_compile(self, shape_key) -> bool:
         """First evaluation at a shape is when jit actually compiles.
@@ -652,7 +698,13 @@ class _TracedNestModel:
     def _bind_params(self, workload_params: WorkloadParams | None
                      ) -> tuple:
         """Validate and lower the workload params to jnp leaves."""
-        wp = workload_params or self.workload_params
+        return self._check_params(
+            workload_params or self.workload_params).device_leaves()
+
+    def _check_params(self, wp: WorkloadParams,
+                      rows: int | None = None) -> WorkloadParams:
+        """Raise unless ``wp`` fits this program: one workload's params,
+        or with ``rows`` that many stacked along a leading axis."""
         if wp.caps != self.caps:
             raise ValueError(
                 f"workload_params caps {wp.caps} != program caps "
@@ -664,11 +716,12 @@ class _TracedNestModel:
                 "workload_params were packed for a different workload "
                 "structure (rank names / projections / output) than "
                 "this program's — metrics would be silently wrong")
-        if len(wp.rank_bounds) != len(self.ranks) or \
-                len(wp.model_ids) != len(self.workload.tensors):
+        lead = () if rows is None else (rows,)
+        if wp.rank_bounds.shape != lead + (len(self.ranks),) or \
+                wp.model_ids.shape != lead + (len(self.workload.tensors),):
             raise ValueError("workload_params shape does not match the "
                              "program's workload structure")
-        return wp.device_leaves()
+        return wp
 
     def _bind_arch(self, arch_params: ArchParams | None, n: int) -> tuple:
         """Validate arch params against the program's topology and
@@ -1301,6 +1354,13 @@ class BatchedModel(_TracedNestModel):
                 bounds.shape, len(bounds))
 
 
+#: rows per call of the row path (:meth:`BucketedModel.evaluate_rows`):
+#: every call has this one candidate-axis shape, so a program compiles
+#: its row variant once whatever the row count; the last block is
+#: padded by repeating its last row
+ROW_BLOCK = 256
+
+
 class BucketedModel(_TracedNestModel):
     """Compiled batched evaluator for one (design, workload, bucket).
 
@@ -1454,20 +1514,7 @@ class BucketedModel(_TracedNestModel):
         mixed-design co-search population rides this one program;
         ``mesh`` shards the candidate axis exactly as in
         :meth:`BatchedModel.evaluate`."""
-        bounds = np.asarray(bounds)
-        rank_ids = np.asarray(rank_ids)
-        if bounds.ndim != 2 or bounds.shape[1] != self.num_slots:
-            raise ValueError(
-                f"bounds must be (C, {self.num_slots}), "
-                f"got {bounds.shape}")
-        if rank_ids.shape != bounds.shape:
-            raise ValueError(
-                f"rank_ids shape {rank_ids.shape} != bounds shape "
-                f"{bounds.shape}")
-        if rank_ids.min(initial=0) < 0 or \
-                rank_ids.max(initial=0) >= len(self.ranks):
-            raise ValueError(f"rank_ids out of range [0, "
-                             f"{len(self.ranks)})")
+        bounds, rank_ids = self._check_population(bounds, rank_ids)
         with enable_x64():
             wp = self._bind_params(workload_params)
             storage, comp = self._bind_arch(arch_params, len(bounds))
@@ -1492,6 +1539,67 @@ class BucketedModel(_TracedNestModel):
                  jnp.asarray(rank_ids, jnp.int64),
                  (jnp.asarray(storage), jnp.asarray(comp))), wp,
                 bounds.shape, len(bounds))
+
+    def evaluate_rows(self, bounds, rank_ids,
+                      workload_params: WorkloadParams
+                      ) -> dict[str, np.ndarray]:
+        """Row path: like :meth:`evaluate` on the facade's own design,
+        but candidate i evaluates under its OWN workload — row i of
+        ``workload_params``, stacked by :func:`stack_workload_params` for
+        workloads of this program's structure and caps — so rows of
+        different workloads share a call.
+
+        The rows run ``ROW_BLOCK`` at a time through the program record's
+        row variant (the same traced step, its workload params vmapped
+        too), the last block padded by repeating its last row, so every
+        call has one shape and the variant compiles once.  Returns (C,)
+        metric arrays with the padding stripped; padded rows are not
+        counted as evaluations."""
+        bounds, rank_ids = self._check_population(bounds, rank_ids)
+        C = len(bounds)
+        if not C:
+            raise ValueError("evaluate_rows needs at least one row")
+        with enable_x64():
+            self._check_params(workload_params, rows=C)
+            storage, comp = self._bind_arch(None, C)
+            compile_stats.record_batched_evals(C,
+                                               shared=self.program_shared)
+            arrs, _ = self._pad_to_multiple(
+                [bounds, rank_ids, storage, comp,
+                 *workload_params.leaves()], ROW_BLOCK)
+            fn = self._prog.row_program()
+            outs = []
+            for start in range(0, len(arrs[0]), ROW_BLOCK):
+                b, ids, st, cp, *wl = (a[start:start + ROW_BLOCK]
+                                       for a in arrs)
+                outs.append(self._run(
+                    fn,
+                    (jnp.asarray(b, jnp.float64),
+                     jnp.asarray(ids, jnp.int64),
+                     (jnp.asarray(st), jnp.asarray(cp))),
+                    tuple(jnp.asarray(x) for x in wl),
+                    ("rows", b.shape), min(ROW_BLOCK, C - start)))
+        return {k: np.concatenate([o[k] for o in outs])[:C]
+                for k in outs[0]}
+
+    def _check_population(self, bounds, rank_ids
+                          ) -> tuple[np.ndarray, np.ndarray]:
+        """Validate matching (C, num_slots) bounds and rank ids."""
+        bounds = np.asarray(bounds)
+        rank_ids = np.asarray(rank_ids)
+        if bounds.ndim != 2 or bounds.shape[1] != self.num_slots:
+            raise ValueError(
+                f"bounds must be (C, {self.num_slots}), "
+                f"got {bounds.shape}")
+        if rank_ids.shape != bounds.shape:
+            raise ValueError(
+                f"rank_ids shape {rank_ids.shape} != bounds shape "
+                f"{bounds.shape}")
+        if rank_ids.min(initial=0) < 0 or \
+                rank_ids.max(initial=0) >= len(self.ranks):
+            raise ValueError(f"rank_ids out of range [0, "
+                             f"{len(self.ranks)})")
+        return bounds, rank_ids
 
 
 # ----------------------------------------------------------------------

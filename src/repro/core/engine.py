@@ -63,6 +63,19 @@ class Evaluation:
         return self.result.edp
 
 
+def _scatter(out: dict, n: int, idxs, res: dict) -> None:
+    """Write a group's result columns into ``out``'s (n, ...) columns
+    at the input positions ``idxs``."""
+    for k, v in res.items():
+        v = np.asarray(v)
+        if k not in out:
+            # some columns carry trailing axes (e.g. per-level occupancy
+            # is (C, S))
+            out[k] = np.zeros((n,) + v.shape[1:],
+                              dtype=bool if k == "valid" else np.float64)
+        out[k][idxs] = v
+
+
 class Sparseloop:
     """The analytical model.  Fast because it is statistical: it never
     iterates the computation space (Sec. 6.2).
@@ -163,17 +176,6 @@ class Sparseloop:
         outs: list[dict[str, np.ndarray]] = [{}
                                              for _ in arch_params_list]
 
-        def scatter(out, idxs, res):
-            for k, v in res.items():
-                v = np.asarray(v)
-                if k not in out:
-                    # some columns carry trailing axes (e.g. per-level
-                    # occupancy is (C, S))
-                    out[k] = np.zeros(
-                        (len(nests),) + v.shape[1:],
-                        dtype=bool if k == "valid" else np.float64)
-                out[k][idxs] = v
-
         with obs.span("engine.batch", candidates=len(nests)) as sp:
             if not bucketed:
                 groups = group_by_template(nests)
@@ -184,8 +186,8 @@ class Sparseloop:
                     bounds = np.stack([template.bounds_of(nests[i])
                                        for i in idxs])
                     for out, ap in zip(outs, arch_params_list):
-                        scatter(out, idxs,
-                                model.evaluate(bounds, arch_params=ap))
+                        _scatter(out, len(nests), idxs,
+                                 model.evaluate(bounds, arch_params=ap))
                 return outs
 
             ranks = tuple(workload.rank_bounds)
@@ -196,9 +198,56 @@ class Sparseloop:
                                             check_capacity, caps=caps)
                 bounds, ids, order = lower_nests(bucket, nests, idxs)
                 for out, ap in zip(outs, arch_params_list):
-                    scatter(out, order,
-                            model.evaluate(bounds, ids, arch_params=ap))
+                    _scatter(out, len(nests), order,
+                             model.evaluate(bounds, ids, arch_params=ap))
             return outs
+
+    def evaluate_rows(self, workloads: Sequence[Workload],
+                      nests: Sequence[LoopNest],
+                      check_capacity: bool = True
+                      ) -> list[dict[str, np.ndarray]]:
+        """Evaluate one mapping per workload: row i is ``nests[i]`` on
+        ``workloads[i]``, the workloads all of one structure.
+
+        Where :meth:`evaluate_network` makes a call per workload, this
+        puts rows of different workloads on one candidate axis: every
+        row's params are packed against the rows' ``common_caps`` and
+        stacked, the rows grouped by bucket, and each bucket's rows run
+        through its program's row variant in blocks of
+        ``batched.ROW_BLOCK`` (``BucketedModel.evaluate_rows``), so any
+        row count costs one compile per bucket.  Returns one result
+        dict per row, in input order.  The ``engine.batch`` span counts
+        the ``rows``, the ``padded`` rows that fill the last block of
+        each bucket, and the ``blocks`` (program calls)."""
+        from .batched import (ROW_BLOCK, common_caps, group_by_bucket,
+                              lower_nests, pack_workload_params,
+                              stack_workload_params)
+        workloads = list(workloads)
+        nests = list(nests)
+        if len(workloads) != len(nests):
+            raise ValueError(f"{len(workloads)} workloads but "
+                             f"{len(nests)} nests")
+        if not nests:
+            return []
+        out: dict[str, np.ndarray] = {}
+        with obs.span("engine.batch", rows=len(nests)) as sp:
+            caps = common_caps(workloads)
+            params = [pack_workload_params(wl, caps) for wl in workloads]
+            groups = group_by_bucket(nests, tuple(workloads[0].rank_bounds))
+            blocks = 0
+            for bucket, idxs in groups.items():
+                model = self.bucketed_model(workloads[idxs[0]], bucket,
+                                            check_capacity, caps=caps)
+                bounds, ids, order = lower_nests(bucket, nests, idxs)
+                res = model.evaluate_rows(
+                    bounds, ids,
+                    stack_workload_params([params[i] for i in order]))
+                _scatter(out, len(nests), order, res)
+                blocks += -(-len(order) // ROW_BLOCK)
+            sp.set(groups=len(groups), blocks=blocks,
+                   padded=blocks * ROW_BLOCK - len(nests))
+        return [{k: v[i] for k, v in out.items()}
+                for i in range(len(nests))]
 
     def evaluate_network(self, workloads: Sequence[Workload],
                          nests_per_workload,

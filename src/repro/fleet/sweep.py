@@ -5,7 +5,7 @@ This is the paper's DSE loop (Sec. 7) scaled from one accelerator and a
 handful of workloads to the whole model fleet: every per-layer matmul of
 every ``repro/configs/`` architecture, prefill and decode, dense vs each
 N:M compression option, evaluated through
-``Sparseloop.evaluate_network`` so the entire sweep costs O(#options x
+``Sparseloop.evaluate_rows`` so the entire sweep costs O(#options x
 #buckets) XLA compiles — *independent of config count, layer count, and
 phase count*.  Three structural facts make that bound hold, and
 :func:`compile_bound` computes it from them up front so CI can gate on
@@ -13,10 +13,12 @@ phase count*.  Three structural facts make that bound hold, and
 
 * ``advisor.tpu_mapping`` keeps unit-bound loops, so every matmul shape
   in the fleet lowers into ONE padded-template bucket per design;
-* workload rank bounds and density parameters are traced inputs
-  (PR 4), so different shapes bind the same program;
+* workload rank bounds and density parameters are traced inputs, one
+  row of them per shape, so different shapes bind the same program —
+  and share its calls, ``batched.ROW_BLOCK`` shapes to a call, each
+  call of the one block shape;
 * uniform/structured density models need no static capacity padding
-  (``DensityCaps(0,0,0)``), so *separate* ``evaluate_network`` calls —
+  (``DensityCaps(0,0,0)``), so *separate* ``evaluate_rows`` calls —
   crossover grids, repeat sweeps, subset sweeps — still share programs.
 
 Identical shapes are deduplicated before evaluation (`dedupe_shapes`):
@@ -109,19 +111,19 @@ def dedupe_shapes(entries: Sequence[LayerMatmul]
 
 def _evaluate_shapes(option: SweepOption, shapes, *,
                      check_capacity: bool = False) -> list[dict]:
-    """One result dict per shape, via the batched network path (one
-    single-candidate population per unique shape)."""
+    """One result dict per shape, via the batched row path (each shape
+    one row, all shapes in a few calls of one program)."""
     if not shapes:
         return []
     engine = Sparseloop(option.design)
     workloads = [matmul(M, K, N, densities=option.densities)
                  for M, K, N in shapes]
-    nests = [[tpu_mapping(M, K, N)] for M, K, N in shapes]
-    outs = engine.evaluate_network(workloads, nests,
-                                   check_capacity=check_capacity)
-    return [{"cycles": float(o["cycles"][0]),
-             "energy_pj": float(o["energy_pj"][0]),
-             "edp": float(o["edp"][0])} for o in outs]
+    nests = [tpu_mapping(M, K, N) for M, K, N in shapes]
+    outs = engine.evaluate_rows(workloads, nests,
+                                check_capacity=check_capacity)
+    return [{"cycles": float(o["cycles"]),
+             "energy_pj": float(o["energy_pj"]),
+             "edp": float(o["edp"])} for o in outs]
 
 
 def compile_bound(options: Sequence[SweepOption], entries,

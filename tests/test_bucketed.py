@@ -402,6 +402,45 @@ def test_workload_params_caps_mismatch_raises():
         bm.evaluate(bounds, ids, workload_params=other)
 
 
+def test_stack_workload_params_rejects_mixed_rows():
+    """Rows of different workloads share one candidate axis only when
+    they share the program's caps and structure; the row path refuses
+    a stack whose row count is not the population's."""
+    import dataclasses
+    from repro.core.batched import (DensityCaps, pack_workload_params,
+                                    stack_workload_params)
+    from repro.core.workload import TensorSpec
+    a = pack_workload_params(matmul(8, 16, 32))
+    b = pack_workload_params(matmul(64, 16, 8,
+                                    densities={"B": ("uniform", 0.5)}))
+    stacked = stack_workload_params([a, b])
+    assert stacked.rank_bounds.shape == (2, 3)
+    assert stacked.caps == a.caps and stacked.structure == a.structure
+    np.testing.assert_array_equal(stacked.rank_bounds[1], b.rank_bounds)
+    np.testing.assert_array_equal(stacked.density_params[1],
+                                  b.density_params)
+    wide = pack_workload_params(matmul(8, 16, 32),
+                                caps=DensityCaps(hist=64))
+    with pytest.raises(ValueError, match="caps"):
+        stack_workload_params([a, wide])
+    # same rank names and tensor count, B stored (n, k): the arrays
+    # would stack, the structure check refuses
+    wl = matmul(8, 16, 32)
+    transposed = dataclasses.replace(wl, tensors=(
+        wl.tensors[0], TensorSpec("B", (("n",), ("k",))), wl.tensors[2]))
+    with pytest.raises(ValueError, match="structure"):
+        stack_workload_params([a, pack_workload_params(transposed)])
+    with pytest.raises(ValueError):
+        stack_workload_params([])
+    design = dense_design(two_level_arch(buffer_kwords=58))
+    enc, pop = _population(WL, 2, CONS, 4, key=41)
+    bucket, bounds, ids = enc.decode_bucketed(pop)
+    bm = get_bucketed_model(design, WL, bucket, check_capacity=False)
+    one = stack_workload_params([pack_workload_params(WL)])
+    with pytest.raises(ValueError, match="shape"):
+        bm.evaluate_rows(bounds, ids, one)
+
+
 def test_program_cache_never_serves_stale_energies():
     """Regression (cache-key audit): two designs differing ONLY in a
     derived-default-adjacent scalar (gated_energy_pj) share one traced

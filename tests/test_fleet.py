@@ -17,17 +17,22 @@ The load-bearing claims, each pinned exactly:
 * **compile accounting** — a REDUCED sweep stays within its structural
   compile bound with zero scalar-path evaluations, and the batched
   results match the scalar reference oracle;
+* **row path** — every shape of an option on one candidate axis gives
+  exactly the per-shape network path's results, in one program call
+  per ``ROW_BLOCK`` shapes;
 * **validation arms** — the deterministic (no wall-clock) arms of the
   kernel-agreement harness pass: N:M packed-bytes traffic sign and
   kernel correctness.
 """
 import dataclasses
 
+import numpy as np
 import pytest
 
 from repro.configs import ARCH_NAMES, get_config
 from repro.core import compile_stats
 from repro.core.advisor import LayerAdvice, advise, tpu_mapping
+from repro.core.batched import ROW_BLOCK
 from repro.core.engine import Sparseloop
 from repro.core.workload import matmul
 from repro.fleet.extract import (MeshSpec, extract_network,
@@ -328,6 +333,83 @@ def test_crossover_values_on_grid():
         for opt, last_win in per_opt.items():
             assert opt in rep.option_names
             assert last_win is None or last_win in grid
+
+
+# ----------------------------------------------------------------------
+# row path: every shape of an option on one candidate axis
+# ----------------------------------------------------------------------
+
+def _per_shape(option, shapes, *, check_capacity=False):
+    """The sweep's evaluation through the per-shape network path: one
+    single-candidate population, and one program call, per shape."""
+    outs = Sparseloop(option.design).evaluate_network(
+        [matmul(M, K, N, densities=option.densities)
+         for M, K, N in shapes],
+        [[tpu_mapping(M, K, N)] for M, K, N in shapes],
+        check_capacity=check_capacity)
+    return [{k: float(o[k][0]) for k in ("cycles", "energy_pj", "edp")}
+            for o in outs]
+
+
+def _fleet_like_shapes(n: int, seed: int) -> list[tuple[int, int, int]]:
+    rng = np.random.default_rng(seed)
+    return [(int(rng.choice((8, 16, 256, 1024, 4096))),
+             int(rng.choice((128, 512, 2048, 4096, 8192))),
+             int(rng.choice((64, 128, 640, 2048, 5504))))
+            for _ in range(n)]
+
+
+@pytest.mark.parametrize("rows", [1, ROW_BLOCK + 44])
+@pytest.mark.parametrize("option", ["dense", "nm-2:4", "nm-2:8"])
+def test_row_path_matches_per_shape_network(option, rows):
+    # ROW_BLOCK + 44 rows: two blocks, the second padded
+    opt = {o.name: o for o in default_options()}[option]
+    shapes = _fleet_like_shapes(rows, seed=rows)
+    wls = [matmul(*s, densities=opt.densities) for s in shapes]
+    nests = [tpu_mapping(*s) for s in shapes]
+    engine = Sparseloop(opt.design)
+    with compile_stats.track() as st:
+        got = engine.evaluate_rows(wls, nests, check_capacity=False)
+    assert st.batched_evals == rows            # padding is not counted
+    want = engine.evaluate_network(wls, [[n] for n in nests],
+                                   check_capacity=False)
+    assert len(got) == rows
+    for g, w in zip(got, want):
+        for k in ("cycles", "energy_pj", "edp", "valid"):
+            assert g[k] == w[k][0], k
+
+
+def test_sweep_row_path_unchanged_and_counted(monkeypatch):
+    from repro import obs
+    from repro.fleet import sweep
+    names = ("qwen3-4b", "xlstm-350m")
+    kw = dict(reduced=True, seq_len=32, batch=2, crossover=True,
+              crossover_grid=(8, 64, 512))
+    tr = obs.enable()
+    try:
+        with compile_stats.track() as st:
+            rep = fleet_sweep(names, **kw)
+    finally:
+        obs.disable()
+    monkeypatch.setattr(sweep, "_evaluate_shapes", _per_shape)
+    ref = fleet_sweep(names, **kw)
+    assert ([dataclasses.asdict(r) for r in rep.rows]
+            == [dataclasses.asdict(r) for r in ref.rows])
+    assert rep.crossover == ref.crossover and rep.crossover
+    assert st.compiles <= rep.compile_bound == len(rep.option_names)
+    # one evaluation per option, then one per option over the grid
+    rows = [s.attrs["shapes"] for s in tr.find("fleet.option")]
+    (cross,) = tr.find("fleet.crossover")
+    rows += [cross.attrs["kn_shapes"] * cross.attrs["grid"]] \
+        * len(rep.option_names)
+    assert len(tr.find("engine.dispatch")) == sum(
+        -(-n // ROW_BLOCK) for n in rows)
+    batches = tr.find("engine.batch")
+    assert sorted(b.attrs["rows"] for b in batches) == sorted(rows)
+    for b in batches:
+        assert b.attrs["blocks"] == -(-b.attrs["rows"] // ROW_BLOCK)
+        assert (b.attrs["padded"]
+                == b.attrs["blocks"] * ROW_BLOCK - b.attrs["rows"])
 
 
 # ----------------------------------------------------------------------

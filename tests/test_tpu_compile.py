@@ -5,8 +5,9 @@ program) refuses things that interpret mode on the CPU accepts: block
 shapes not divisible by (8, 128), vector shape casts of narrow integer
 types, programs that do not fit.  Each test here compiles one kernel at
 the widths the modeler's users run (qwen3-4b MLP: 256 x 2560 x 9728;
-attention at S=4096, D=128), or the bucket program at 1024 candidates,
-for one chip of a described ``v5e:2x2``.  Nothing runs.
+attention at S=4096, D=128), the bucket program at 1024 candidates, or
+its row variant at one block of fleet shapes, for one chip of a
+described ``v5e:2x2``.  Nothing runs.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -125,6 +126,46 @@ def test_bucket_program_compiles_for_v5e_at_1024(one_chip):
                  sds(np.asarray(ids, np.int64)), (sds(storage), sds(comp))),
                 tuple(sds(x) for x in bm._bind_params(None)))
         lowered = bm._prog.fn.lower(*args)
+        assert lowered.as_text().count("chlo.lgamma") == 1
+        compiled = lowered.compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_row_program_compiles_for_v5e(one_chip):
+    """The bucket program's row variant, as the fleet sweep calls it:
+    one block of 2:4 shapes, each row with its own workload params (the
+    density kind's switch is then vmapped too), still one gammaln."""
+    import jax
+    from repro.core.advisor import tpu_mapping
+    from repro.core.batched import (ROW_BLOCK, bucket_for, lower_nests,
+                                    pack_workload_params,
+                                    stack_workload_params, template_of)
+    from repro.core.engine import Sparseloop
+    from repro.core.workload import matmul
+    from repro.fleet.sweep import nm_option
+
+    opt = nm_option(2, 4)
+    shapes = [(8 * (i + 1), 512 * (1 + i % 4), 1024)
+              for i in range(ROW_BLOCK)]
+    wls = [matmul(*s, densities=opt.densities) for s in shapes]
+    nests = [tpu_mapping(*s) for s in shapes]
+    bucket = bucket_for(template_of(nests[0]), tuple(wls[0].rank_bounds))
+    bm = Sparseloop(opt.design).bucketed_model(wls[0], bucket,
+                                               check_capacity=False)
+    bounds, ids, _ = lower_nests(bucket, nests, range(ROW_BLOCK))
+    wp = stack_workload_params(
+        [pack_workload_params(wl, bm.caps) for wl in wls])
+
+    def sds(x):
+        x = np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    with jax.enable_x64():
+        storage, comp = bm._bind_arch(None, ROW_BLOCK)
+        args = ((sds(np.asarray(bounds, np.float64)),
+                 sds(np.asarray(ids, np.int64)), (sds(storage), sds(comp))),
+                tuple(sds(x) for x in wp.leaves()))
+        lowered = bm._prog.row_program().lower(*args)
         assert lowered.as_text().count("chlo.lgamma") == 1
         compiled = lowered.compile()
     assert compiled.memory_analysis() is not None
