@@ -32,7 +32,7 @@ import json
 import sys
 
 from repro.core import compile_stats
-from repro.fleet.sweep import fleet_sweep
+from repro.fleet.sweep import default_options, dsa_option, fleet_sweep
 from repro.fleet.validate import (agreement_summary, validate_fleet)
 
 from .common import emit
@@ -91,21 +91,27 @@ def _assert_sweep(rep, st) -> None:
 
 
 def compile_gate() -> list[tuple[str, float, str]]:
-    """Full 10-config fleet sweep under a hard, config- and layer-count
+    """Full fleet sweep (every config) under a hard, config- and layer-count
     independent compile budget, then a subset re-sweep that must be
     entirely warm (zero additional compiles)."""
-    from repro.configs import ARCH_NAMES
+    from repro.configs import ARCH_NAMES, get_config
     with compile_stats.track() as st:
         rep = fleet_sweep()          # all configs, prefill+decode
     print(rep.summary())
     _assert_sweep(rep, st)
-    n_options = len(rep.option_names)
-    assert rep.compile_bound == n_options, (
-        f"compile bound {rep.compile_bound} != option count {n_options}:"
+    # one program per design point of the options the sweep ran
+    n_designs = sum(len(o.designs())
+                    for o in (*default_options(), dsa_option())
+                    if o.name in rep.option_names)
+    assert rep.compile_bound == n_designs, (
+        f"compile bound {rep.compile_bound} != design count {n_designs}:"
         f" tpu_mapping no longer lowers every fleet shape into one "
         f"bucket per design")
 
-    subset = ARCH_NAMES[:2]
+    # programs are keyed by whether their rows hold a selection (DSA):
+    # a subset with the same density families reuses every program
+    subset = ARCH_NAMES[:2] + tuple(
+        n for n in ARCH_NAMES if get_config(n).dsa is not None)
     with compile_stats.track() as st2:
         rep2 = fleet_sweep(subset)
     print(f"subset re-sweep ({len(subset)} configs): {st2.compiles} "

@@ -16,6 +16,7 @@ _MODULES = {
     "xlstm-350m": "xlstm_350m",
     "internvl2-76b": "internvl2_76b",
     "zamba2-7b": "zamba2_7b",
+    "deepseek-v3.2-exp": "deepseek_v32_exp",
 }
 
 ARCH_NAMES = tuple(_MODULES)

@@ -20,6 +20,9 @@ Supported models (Table 4):
                      tensor.  Coordinate *dependent*.
   actual           : wraps a concrete numpy array; exact empirical tile
                      statistics.  Coordinate dependent, non-statistical.
+  selected         : exactly k of L fibres hold data, the same k along
+                     the whole dense rank (a top-k selection, e.g.
+                     DeepSeek Sparse Attention's selected cache tokens).
 
 All prob/expectation math is done in log-space (lgamma) so it is both
 numerically stable and usable from inside jitted/vmapped mapper code.
@@ -62,8 +65,10 @@ from typing import Sequence
 import numpy as np
 
 #: density-model kind ids (the ``lax.switch`` index of TracedDensityStats)
-DENSE_ID, UNIFORM_ID, STRUCTURED_ID, BANDED_ID, ACTUAL_ID = range(5)
-MODEL_KINDS = ("dense", "uniform", "structured", "banded", "actual")
+DENSE_ID, UNIFORM_ID, STRUCTURED_ID, BANDED_ID, ACTUAL_ID, SELECTED_ID = \
+    range(6)
+MODEL_KINDS = ("dense", "uniform", "structured", "banded", "actual",
+               "selected")
 
 #: fixed length of every model's traced parameter vector
 NUM_DENSITY_PARAMS = 4
@@ -111,8 +116,9 @@ class DensityCaps:
     row count of any banded tensor (row-scan length), ``div`` >= the
     isqrt of any banded tensor's size (tile-shape divisor scan), and
     ``hist`` >= the size of any actual-data tensor (histogram table
-    length).  Zero means "no tensor of that family" and prunes the
-    corresponding ``lax.switch`` branch entirely.  Caps are part of a
+    length), and ``select`` is 1 where some tensor is a selection.  Zero
+    means "no tensor of that family" and prunes the corresponding
+    ``lax.switch`` branch entirely.  Caps are part of a
     compiled program's cache key; :func:`caps_for_models` rounds them up
     to powers of two so layers of similar size land on the same program.
     """
@@ -120,15 +126,17 @@ class DensityCaps:
     coord: int = 0
     div: int = 0
     hist: int = 0
+    select: int = 0
 
     def merge(self, other: "DensityCaps") -> "DensityCaps":
         return DensityCaps(coord=max(self.coord, other.coord),
                            div=max(self.div, other.div),
-                           hist=max(self.hist, other.hist))
+                           hist=max(self.hist, other.hist),
+                           select=max(self.select, other.select))
 
     def covers(self, need: "DensityCaps") -> bool:
         return (self.coord >= need.coord and self.div >= need.div
-                and self.hist >= need.hist)
+                and self.hist >= need.hist and self.select >= need.select)
 
 
 def _pow2_cap(n: int) -> int:
@@ -139,17 +147,21 @@ def caps_for_models(models: Sequence["DensityModel"],
                     round_pow2: bool = True) -> DensityCaps:
     """The smallest :class:`DensityCaps` covering ``models`` (rounded up
     to powers of two by default, so similarly-sized layers share)."""
-    coord = div = hist = 0
+    coord = div = hist = select = 0
     for m in models:
-        if isinstance(m, BandedModel):
+        # by kind id, not isinstance: this runs for every row of a sweep
+        kind = m.kind_id
+        if kind == BANDED_ID:
             coord = max(coord, m.rows)
             div = max(div, max(1, math.isqrt(max(1, m.rows * m.cols))))
-        elif isinstance(m, ActualDataModel):
+        elif kind == ACTUAL_ID:
             hist = max(hist, m.tensor_size)
+        elif kind == SELECTED_ID:
+            select = 1
     if round_pow2:
         coord, div, hist = (_pow2_cap(coord), _pow2_cap(div),
                             _pow2_cap(hist))
-    return DensityCaps(coord=coord, div=div, hist=hist)
+    return DensityCaps(coord=coord, div=div, hist=hist, select=select)
 
 
 # ----------------------------------------------------------------------
@@ -335,6 +347,50 @@ def banded_max_nnz_t(p, h, t, caps: DensityCaps):
     return jnp.where(best > 0, jnp.minimum(ti, best), fallback) * 1.0
 
 
+#: a tile that spans at most this many fibres of a selection takes its
+#: empty probability as the exact product prod_j (1 - k / (L - j)); the
+#: hypergeometric term's log-gamma differences of L-sized arguments lose
+#: ~1e-9 of it, which 1 - P(empty) (about k / L) magnifies
+SELECT_PRODUCT = 64
+
+
+def _selected_fibres_t(p, t):
+    """Fibres a tile of ``t`` elements spans: ceil(t / fibre), at most L."""
+    import jax.numpy as jnp
+    return jnp.minimum(jnp.ceil(t * 1.0 / p[3]), p[1])
+
+
+def selected_prob_empty_split_t(p, h, t):
+    """params: [tensor_size, L, k, fibre]."""
+    import jax.numpy as jnp
+    del h
+    L, k = p[1], p[2]
+    c = _selected_fibres_t(p, t)
+    j = jnp.arange(SELECT_PRODUCT, dtype=c.dtype)
+    # j < c <= L - k keeps k / (L - j) < 1; past L - k the product is 0
+    x = jnp.minimum(k / jnp.maximum(L - j, 1.0), 1.0)
+    logs = jnp.where(j < c[..., None], jnp.log1p(-x), 0.0)
+    direct = jnp.exp(jnp.sum(logs, axis=-1))
+    return direct, L * jnp.ones_like(c), k * jnp.ones_like(c), c, \
+        c > SELECT_PRODUCT
+
+
+def selected_prob_empty_t(p, h, t):
+    return _combine_pe(*selected_prob_empty_split_t(p, h, t))
+
+
+def selected_expected_density_t(p, h, t):
+    import jax.numpy as jnp
+    del h
+    return jnp.ones_like(t * 1.0) * (p[2] / p[1])
+
+
+def selected_max_nnz_t(p, h, t):
+    import jax.numpy as jnp
+    del h
+    return jnp.minimum(t * 1.0, p[2] * p[3])
+
+
 def _actual_index(p, t):
     """Histogram row for a (clamped) traced tile size; params[0] is the
     valid table length (the concrete array's size)."""
@@ -445,6 +501,12 @@ class TracedDensityStats:
                     with_caps(banded_max_nnz_t) if banded_ok
                     else dense_max_nnz_t,
                     actual_max_nnz_t if actual_ok else dense_max_nnz_t)
+        if caps.select:
+            # no selected tensor: no branch at all, so such programs are
+            # the ones they were before the kind existed
+            self._pe += (selected_prob_empty_split_t,)
+            self._ed += (selected_expected_density_t,)
+            self._mx += (selected_max_nnz_t,)
 
     @staticmethod
     def _switch(branches, kind, params, hist, tile_size):
@@ -897,6 +959,76 @@ class ActualDataModel(DensityModel):
         return actual_max_nnz_t(self.params(), self._hist_b(), tile_size)
 
 
+@dataclasses.dataclass
+class SelectedModel(DensityModel):
+    """A top-k selection: of ``L`` fibres, each ``fibre`` elements long
+    along the tensor's dense rank, exactly ``k`` hold data, at positions
+    drawn uniformly and shared by the whole fibre (aligned across the
+    dense rank).  A tile of t elements spans c = ceil(t / fibre) fibres,
+    so
+
+      P(empty)  = C(L - c, k) / C(L, k)   (uniform's hypergeometric, over
+                                          L fibres instead of elements)
+      E[density] = k / L,   max_nnz = min(t, k * fibre).
+
+    This is exact when a tile holds whole fibres, its extent along the
+    dense rank the rank's whole extent: the fleet sweep's ``tpu_mapping``
+    gives that for both DeepSeek Sparse Attention products, whose
+    selected operand is the latent cache, since its fibre fits one VMEM
+    tile — a 576-long column in the score product (K <= bk), a 512-long
+    row in the value product (N <= bn).  Narrower tiles are
+    undercounted.  A spec's ``rank`` (which rank is selected) is the
+    design's concern, not the model's."""
+
+    tensor_size: int
+    L: int
+    k: int
+    fibre: int
+    batched = True
+    kind_id = SELECTED_ID
+
+    def __post_init__(self) -> None:
+        if not 0 < self.k <= self.L or self.L * self.fibre != \
+                self.tensor_size:
+            raise ValueError(
+                f"a selection of {self.k} of {self.L} fibres of "
+                f"{self.fibre} does not fit a tensor of {self.tensor_size}")
+
+    @property
+    def density(self) -> float:  # type: ignore[override]
+        return self.k / self.L
+
+    def fibres(self, tile_size: int) -> int:
+        return min(math.ceil(tile_size / self.fibre), self.L)
+
+    def prob_empty(self, tile_size: int) -> float:
+        L, k, c = self.L, self.k, self.fibres(tile_size)
+        if c > L - k:
+            return 0.0
+        if c <= SELECT_PRODUCT:
+            return math.exp(sum(math.log1p(-k / (L - j)) for j in range(c)))
+        # C(L-c, k) / C(L, k) written as the hypergeometric term's
+        # C(L-k, c) / C(L, c), as the traced form evaluates it
+        lp = _log_comb(L - k, c) - _log_comb(L, c)
+        return math.exp(lp) if lp > -700 else 0.0
+
+    def max_nnz(self, tile_size: int) -> int:
+        return min(tile_size, self.k * self.fibre)
+
+    def params(self) -> np.ndarray:
+        return np.asarray([self.tensor_size, self.L, self.k, self.fibre],
+                          np.float64)
+
+    def prob_empty_b(self, tile_size):
+        return selected_prob_empty_t(self.params(), None, tile_size)
+
+    def expected_density_b(self, tile_size):
+        return selected_expected_density_t(self.params(), None, tile_size)
+
+    def max_nnz_b(self, tile_size):
+        return selected_max_nnz_t(self.params(), None, tile_size)
+
+
 def make_density_model(spec: object, tensor_size: int) -> DensityModel:
     """Build a model from a workload density spec tuple."""
     if spec is None:
@@ -914,4 +1046,7 @@ def make_density_model(spec: object, tensor_size: int) -> DensityModel:
                            half_band=int(arg["half_band"]))
     if kind == "actual":
         return ActualDataModel(data=np.asarray(arg))
+    if kind == "selected":
+        return SelectedModel(tensor_size=tensor_size, L=int(arg["L"]),
+                             k=int(arg["k"]), fibre=int(arg["fibre"]))
     raise ValueError(f"unknown density spec {spec!r}")

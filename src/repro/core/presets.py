@@ -275,3 +275,32 @@ def tpu_nm_design(n: int = 2, m: int = 4) -> Design:
     return Design(arch=tpu_v5e_arch(),
                   safs=SAFSpec(formats=fmts, actions=()),
                   name=f"tpu-nm-{n}:{m}")
+
+
+#: the longest cache a DSA coordinate addresses: DeepSeek-V3.2's
+#: max_position_embeddings
+DSA_MAX_POSITIONS = 163840
+
+
+def tpu_dsa_design(rank: str) -> Design:
+    """DeepSeek Sparse Attention on TPU v5e: the latent cache (operand B
+    of both attention products) holds only the indexer's top-k tokens,
+    along ``rank`` — "n" in the score product (B is 576 x L), "k" in the
+    value product (B is L x 512).  The cache is stored
+    coordinate-payload on that rank and uncompressed on the other (one
+    coordinate per gathered token), in HBM and VMEM; its metadata leads
+    the skipping of the other operand's HBM->VMEM reads and of the
+    compute.  A coordinate addresses :data:`DSA_MAX_POSITIONS`."""
+    if rank not in ("k", "n"):
+        raise ValueError(f"the cache's selected rank is k or n, not {rank!r}")
+    ranks = ((RankFormat.CP, RankFormat.U) if rank == "k"
+             else (RankFormat.U, RankFormat.CP))
+    fmt = TensorFormat.of(*ranks,
+                          coord_bits=(DSA_MAX_POSITIONS - 1).bit_length())
+    safs = SAFSpec(
+        formats={("HBM", "B"): fmt, ("VMEM", "B"): fmt},
+        actions=(
+            ActionSAF(SAFKind.SKIP, "HBM", "A", ("B",)),
+            ActionSAF(SAFKind.SKIP, "compute", "Z", ("B",)),
+        ))
+    return Design(arch=tpu_v5e_arch(), safs=safs, name=f"tpu-dsa-{rank}")

@@ -11,13 +11,14 @@ from .extract import (LayerMatmul, MeshSpec, NetworkWorkloads,
                       extract_fleet, extract_network,
                       production_mesh_spec, shard_entries)
 from .sweep import (FleetReport, LayerVerdict, SweepOption,
-                    default_options, dedupe_shapes, fleet_sweep,
+                    default_options, dedupe_shapes, dsa_option,
+                    fleet_sweep,
                     nm_design_for_weights, nm_option)
 
 __all__ = [
     "LayerMatmul", "MeshSpec", "NetworkWorkloads", "extract_fleet",
     "extract_network", "production_mesh_spec", "shard_entries",
     "FleetReport", "LayerVerdict", "SweepOption", "default_options",
-    "dedupe_shapes", "fleet_sweep", "nm_design_for_weights",
+    "dedupe_shapes", "dsa_option", "fleet_sweep", "nm_design_for_weights",
     "nm_option",
 ]

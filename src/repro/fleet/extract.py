@@ -21,6 +21,14 @@ become ONE entry with ``count=36`` — the evaluation-side dedup
 (`fleet.sweep.dedupe_shapes`) then collapses shape collisions *across*
 entries and configs.
 
+A model with DeepSeek Sparse Attention (``cfg.dsa``) decodes in MLA's
+absorbed form: ``kv_b`` splits into per-head W_UK and W_UV, every head
+scores the one shared 576-wide latent cache (heads in M), and the score
+and value products carry the indexer's selection of ``index_topk`` of
+the cache's tokens as a density (``LayerMatmul.select``); the indexer
+itself scores the whole cache, dense.  Prefill keeps MLA's
+non-absorbed form with dense attention.
+
 Sharding reuses the production resolver: ``shard_entries`` maps each
 entry to its per-device shape under ``launch.sharding.resolve_spec``
 (Megatron-style: column-parallel QKV/up projections split N on
@@ -80,7 +88,14 @@ class LayerMatmul:
     activation-activation products like attention scores — their
     operands are produced, not stored).  ``tp`` tags the tensor-parallel
     style used by ``shard_entries``: "col" splits N, "row" splits K,
-    "none" replicates the weight.
+    "none" replicates the weight, "per_head" splits the per-head
+    ``count``, and "heads" marks an activation product with the heads
+    in M (split like heads, its sequences in ``count``).
+
+    ``select`` is ``(tensor, rank, k)`` for a product whose operand
+    ``tensor`` ("A" or "B") holds only ``k`` of its ``rank`` extent's
+    fibres (a top-k selection); :attr:`densities` states it for the
+    entry's current shape.
     """
 
     name: str
@@ -90,10 +105,26 @@ class LayerMatmul:
     count: int = 1
     param_instances: int = 1
     tp: str = "none"
+    select: tuple[str, str, int] | None = None
 
     @property
     def shape(self) -> tuple[int, int, int]:
         return (self.M, self.K, self.N)
+
+    @property
+    def densities(self) -> dict | None:
+        """Workload density specs of the entry (None: dense): a
+        selection of k of the selected rank's L fibres, each as long as
+        the operand's other rank (``rank`` names the selected rank)."""
+        if self.select is None:
+            return None
+        tensor, rank, k = self.select
+        extent = {"m": self.M, "k": self.K, "n": self.N}
+        other, = set({"A": "mk", "B": "kn"}[tensor]) - {rank}
+        L = extent[rank]
+        return {tensor: ("selected", {"rank": rank, "L": L,
+                                      "k": min(k, L),
+                                      "fibre": extent[other]})}
 
     @property
     def weight_params(self) -> int:
@@ -137,15 +168,62 @@ class NetworkWorkloads:
 # extraction walk (mirrors ModelConfig.param_count branch-for-branch)
 # ----------------------------------------------------------------------
 
+def _mla_q(cfg, T: int) -> list[LayerMatmul]:
+    """MLA's query: one projection, or q-LoRA's down and up."""
+    m, d = cfg.mla, cfg.d_model
+    n = cfg.num_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if not m.q_lora_rank:
+        return [LayerMatmul("mla_q_proj", T, d, n, tp="col")]
+    return [LayerMatmul("mla_q_a", T, d, m.q_lora_rank, tp="none"),
+            LayerMatmul("mla_q_b", T, m.q_lora_rank, n, tp="col")]
+
+
+def _indexer_weights(cfg, T: int) -> list[LayerMatmul]:
+    """DSA's lightning indexer: per-head queries from the query latent,
+    one shared key and a per-head weight from the hidden state."""
+    m, x, d = cfg.mla, cfg.dsa, cfg.d_model
+    return [
+        LayerMatmul("idx_q", T, m.q_lora_rank or d,
+                    x.index_n_heads * x.index_head_dim, tp="col"),
+        LayerMatmul("idx_k", T, d, x.index_head_dim, tp="none"),
+        LayerMatmul("idx_w", T, d, x.index_n_heads, tp="none"),
+    ]
+
+
+def _dsa_decode(cfg, T: int, kv_len: int, n_seq: int) -> list[LayerMatmul]:
+    """One DSA layer's decode step, MLA absorbed: W_UK folds into the
+    query and W_UV into the output, so every head attends over the
+    shared latent cache (``kv_lora_rank`` + rope wide), on the indexer's
+    ``index_topk`` selected tokens of the ``kv_len`` cached.  The cache
+    is operand B of both products, selected along L: its columns in the
+    score product, its rows in the value product (whose probabilities,
+    A, stay dense over the cache)."""
+    m, x, d, h = cfg.mla, cfg.dsa, cfg.d_model, cfg.num_heads
+    latent = m.kv_lora_rank + m.qk_rope_head_dim
+    k = x.index_topk
+    return _mla_q(cfg, T) + [
+        LayerMatmul("mla_kv_a", T, d, latent, tp="none"),
+        LayerMatmul("mla_uk", T, m.qk_nope_head_dim, m.kv_lora_rank,
+                    count=h, param_instances=h, tp="per_head"),
+        LayerMatmul("mla_uv", T, m.kv_lora_rank, m.v_head_dim,
+                    count=h, param_instances=h, tp="per_head"),
+        LayerMatmul("mla_o", T, h * m.v_head_dim, d, tp="row"),
+    ] + _indexer_weights(cfg, T) + [
+        LayerMatmul("idx_scores", x.index_n_heads, x.index_head_dim,
+                    kv_len, count=n_seq, param_instances=0, tp="heads"),
+        LayerMatmul("dsa_qk", h, latent, kv_len, count=n_seq,
+                    param_instances=0, tp="heads", select=("B", "n", k)),
+        LayerMatmul("dsa_av", h, kv_len, m.kv_lora_rank, count=n_seq,
+                    param_instances=0, tp="heads", select=("B", "k", k)),
+    ]
+
+
 def _attn_weights(cfg, T: int) -> list[LayerMatmul]:
     d = cfg.d_model
     if cfg.mla:
         m = cfg.mla
         h = cfg.num_heads
-        return [
-            LayerMatmul("mla_q_proj", T, d,
-                        h * (m.qk_nope_head_dim + m.qk_rope_head_dim),
-                        tp="col"),
+        out = _mla_q(cfg, T) + [
             LayerMatmul("mla_kv_a_proj", T, d,
                         m.kv_lora_rank + m.qk_rope_head_dim, tp="none"),
             LayerMatmul("mla_kv_b_proj", T, m.kv_lora_rank,
@@ -153,6 +231,7 @@ def _attn_weights(cfg, T: int) -> list[LayerMatmul]:
                         tp="col"),
             LayerMatmul("mla_o_proj", T, h * m.v_head_dim, d, tp="row"),
         ]
+        return out + (_indexer_weights(cfg, T) if cfg.dsa else [])
     return [
         LayerMatmul("attn_qkv", T, d, cfg.q_dim + 2 * cfg.kv_dim,
                     tp="col"),
@@ -213,7 +292,7 @@ def _merge(entries: list[LayerMatmul]) -> tuple[LayerMatmul, ...]:
     merged: dict = {}
     order = []
     for e in entries:
-        key = (e.name, e.M, e.K, e.N, e.tp)
+        key = (e.name, e.M, e.K, e.N, e.tp, e.select)
         if key in merged:
             old = merged[key]
             merged[key] = dataclasses.replace(
@@ -234,7 +313,11 @@ def extract_network(cfg, phase: str = "prefill", *,
     prefill: every sequence position is live (T = batch * seq tokens,
     attention is q_len=seq vs kv_len=seq).  decode: one new token per
     sequence (T = batch tokens, attention is q_len=1 vs the kv cache of
-    ``ctx_len`` positions).  ``attn_window`` caps kv_len in both.
+    ``ctx_len`` positions).  ``attn_window`` caps kv_len in both.  A
+    DSA model decodes in MLA's absorbed form (module docstring); at
+    prefill its indexer scores every query against every key, and its
+    attention is the dense product (the selection, which differs per
+    query row there, is not modelled).
     Returns GLOBAL (unsharded) shapes; apply :func:`shard_entries` for
     per-device shapes.
     """
@@ -256,8 +339,15 @@ def extract_network(cfg, phase: str = "prefill", *,
     entries: list[LayerMatmul] = []
     for layer in range(cfg.num_layers):
         kind = cfg.block_kind(layer)
-        if kind == "attn":
+        if kind == "attn" and cfg.dsa and phase == "decode":
+            entries += _dsa_decode(cfg, T, kv_len, batch)
+        elif kind == "attn":
             entries += _attn_weights(cfg, T)
+            if cfg.dsa:
+                x = cfg.dsa
+                entries.append(LayerMatmul(
+                    "idx_scores", q_len, x.index_head_dim, kv_len,
+                    count=x.index_n_heads * batch, param_instances=0))
             entries += _attn_scores(cfg, "attn", q_len, kv_len, batch)
         elif kind == "mamba2":
             di = cfg.ssm_expand * d
@@ -359,14 +449,22 @@ def shard_entries(net: NetworkWorkloads, mesh) -> NetworkWorkloads:
     """Per-device shapes under ``mesh`` (a jax Mesh or MeshSpec).
 
     Token dims (M) split over the data axes; "col" weights split N and
-    "row" weights split K over "model"; attention score counts split
-    heads over "model" and sequences over data.  Indivisible splits
+    "row" weights split K over "model", "per_head" weights their head
+    count; attention score counts split heads over "model" and
+    sequences over data, and "heads" products split their M (the heads)
+    over "model" and their count (the sequences) over data.  Indivisible splits
     replicate (resolve_spec semantics) — shapes never go fractional.
     """
     if mesh is None:
         return net
     out = []
     for e in net.matmuls:
+        if e.tp == "heads":
+            # heads in M on "model", the sequences in count on data
+            out.append(dataclasses.replace(
+                e, M=_shard_dim(e.M, "model", mesh),
+                count=max(1, _shard_dim(e.count, "data", mesh))))
+            continue
         if e.param_instances == 0:
             # count = heads * n_seq * layers; shard the head product on
             # "model" and the sequence product on the data axes
@@ -376,11 +474,14 @@ def shard_entries(net: NetworkWorkloads, mesh) -> NetworkWorkloads:
             continue
         M = max(1, _shard_dim(e.M, "data", mesh))
         K, N = e.K, e.N
+        count = e.count
         if e.tp == "col":
             N = _shard_dim(N, "model", mesh)
         elif e.tp == "row":
             K = _shard_dim(K, "model", mesh)
-        out.append(dataclasses.replace(e, M=M, K=K, N=N))
+        elif e.tp == "per_head":
+            count = _shard_dim(count, "model", mesh)
+        out.append(dataclasses.replace(e, M=M, K=K, N=N, count=count))
     return dataclasses.replace(net, matmuls=tuple(out))
 
 

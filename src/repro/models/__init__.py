@@ -1,5 +1,6 @@
-from .config import HybridConfig, MLAConfig, MoEConfig, ModelConfig
+from .config import (DSAConfig, HybridConfig, MLAConfig, MoEConfig,
+                     ModelConfig)
 from .transformer import ModelApi, get_api, lm_loss_from_hidden
 
-__all__ = ["HybridConfig", "MLAConfig", "MoEConfig", "ModelConfig",
+__all__ = ["DSAConfig", "HybridConfig", "MLAConfig", "MoEConfig", "ModelConfig",
            "ModelApi", "get_api", "lm_loss_from_hidden"]

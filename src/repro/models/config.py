@@ -23,6 +23,9 @@ class MoEConfig:
     capacity_factor: float = 1.25
     #: apply MoE every k-th layer (1 = all layers)
     every: int = 1
+    #: leading layers that keep a dense FFN of ``ModelConfig.d_ff``
+    #: (DeepSeek's ``first_k_dense_replace``)
+    first_dense: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,6 +34,21 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    #: low-rank query: x -> c_q (this rank, RMS-normed) -> heads; 0 = a
+    #: full-rank query projection
+    q_lora_rank: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class DSAConfig:
+    """DeepSeek Sparse Attention (DeepSeek-V3.2-Exp): a lightning indexer
+    of ``index_n_heads`` x ``index_head_dim`` scores every cached token
+    (queries from the MLA query latent, one shared key per token, a
+    per-head weight from the hidden state) and each query attends to its
+    ``index_topk`` best-scored tokens only."""
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +85,7 @@ class ModelConfig:
     # --- families ---
     moe: MoEConfig | None = None
     mla: MLAConfig | None = None
+    dsa: DSAConfig | None = None
     hybrid: HybridConfig | None = None
     block_pattern: tuple[BlockKind, ...] = ()   # xlstm: ("mlstm","slstm")
     # --- ssm ---
@@ -112,7 +131,8 @@ class ModelConfig:
         return "attn"
 
     def is_moe_layer(self, layer: int) -> bool:
-        return self.moe is not None and (layer % self.moe.every == 0)
+        return (self.moe is not None and layer >= self.moe.first_dense
+                and layer % self.moe.every == 0)
 
     # rough parameter count (embeddings + blocks), for reporting
     def param_count(self) -> int:
@@ -126,9 +146,19 @@ class ModelConfig:
                     total += d * (m.kv_lora_rank + m.qk_rope_head_dim)
                     total += m.kv_lora_rank * self.num_heads * (
                         m.qk_nope_head_dim + m.v_head_dim)
-                    total += d * self.num_heads * (
-                        m.qk_nope_head_dim + m.qk_rope_head_dim)
+                    qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+                    if m.q_lora_rank:
+                        total += (d * m.q_lora_rank
+                                  + m.q_lora_rank * self.num_heads * qk)
+                    else:
+                        total += d * self.num_heads * qk
                     total += self.num_heads * m.v_head_dim * d
+                    if self.dsa:
+                        x = self.dsa
+                        total += ((m.q_lora_rank or d) * x.index_n_heads
+                                  * x.index_head_dim
+                                  + d * (x.index_head_dim
+                                         + x.index_n_heads))
                 else:
                     total += d * (self.q_dim + 2 * self.kv_dim) \
                         + self.q_dim * d

@@ -238,35 +238,102 @@ def attention_decode(p, x, cache, cfg: ModelConfig, pos, *,
 
 # ----------------------------------------------------------------------
 # MLA (DeepSeek-V2): low-rank compressed KV — cache stores (c_kv, k_rope)
+# DSA (DeepSeek-V3.2-Exp): a lightning indexer picks each query's
+# top-k cached tokens; the cache also keeps the indexer's keys
 # ----------------------------------------------------------------------
 def init_mla(cfg: ModelConfig, key):
     m = cfg.mla
     d, H = cfg.d_model, cfg.num_heads
+    qk = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 5)
+    ks = [*ks, *jax.random.split(jax.random.fold_in(key, 1), 4)]
     p = {
         "w_dkv": _init(ks[0], (d, m.kv_lora_rank + m.qk_rope_head_dim)),
         "w_uk": _init(ks[1], (m.kv_lora_rank, H, m.qk_nope_head_dim)),
         "w_uv": _init(ks[2], (m.kv_lora_rank, H, m.v_head_dim)),
-        "w_q": _init(ks[3], (d, H, m.qk_nope_head_dim + m.qk_rope_head_dim)),
         "wo": _init(ks[4], (H * m.v_head_dim, d), scale_axis=0),
         "kv_norm": jnp.ones((m.kv_lora_rank,)),
     }
     spec = {
         "w_dkv": P(None, None), "w_uk": P(None, MODEL, None),
-        "w_uv": P(None, MODEL, None), "w_q": P(None, MODEL, None),
+        "w_uv": P(None, MODEL, None),
         "wo": P(MODEL, None), "kv_norm": P(None),
     }
+    if m.q_lora_rank:
+        p["w_dq"] = _init(ks[3], (d, m.q_lora_rank))
+        p["q_norm"] = jnp.ones((m.q_lora_rank,))
+        p["w_uq"] = _init(ks[5], (m.q_lora_rank, H, qk))
+        spec.update(w_dq=P(None, None), q_norm=P(None),
+                    w_uq=P(None, MODEL, None))
+    else:
+        p["w_q"] = _init(ks[3], (d, H, qk))
+        spec["w_q"] = P(None, MODEL, None)
+    if cfg.dsa:
+        x = cfg.dsa
+        p["idx_wq"] = _init(ks[6], (m.q_lora_rank or d, x.index_n_heads,
+                                    x.index_head_dim))
+        p["idx_wk"] = _init(ks[7], (d, x.index_head_dim))
+        p["idx_k_norm"] = {"scale": jnp.ones((x.index_head_dim,)),
+                           "bias": jnp.zeros((x.index_head_dim,))}
+        p["idx_ww"] = _init(ks[8], (d, x.index_n_heads))
+        spec.update(idx_wq=P(None, None, None), idx_wk=P(None, None),
+                    idx_k_norm={"scale": P(None), "bias": P(None)},
+                    idx_ww=P(None, None))
     return p, spec
 
 
 def _mla_q(p, x, cfg, positions):
+    """(q_nope, q_rope, q_src): the query's two parts per head, and what
+    the indexer's queries read (q-LoRA's normed latent, else x)."""
     m = cfg.mla
-    B, S, _ = x.shape
-    q = jnp.einsum("bsd,dhe->bshe", x, p["w_q"].astype(x.dtype))
+    if m.q_lora_rank:
+        src = apply_norm({"scale": p["q_norm"]},
+                         x @ p["w_dq"].astype(x.dtype))
+        q = jnp.einsum("bsr,rhe->bshe", src, p["w_uq"].astype(x.dtype))
+    else:
+        src = x
+        q = jnp.einsum("bsd,dhe->bshe", x, p["w_q"].astype(x.dtype))
     q_nope = q[..., :m.qk_nope_head_dim]
     q_rope = apply_rope(q[..., m.qk_nope_head_dim:], positions,
                         cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, q_rope, src
+
+
+def _idx_rope(x, positions, cfg):
+    """Rotary embedding on the indexer head's first ``qk_rope_head_dim``
+    features."""
+    d = x.shape[-1]
+    return apply_rope(x, positions, cfg.rope_theta,
+                      pct=cfg.mla.qk_rope_head_dim / d)
+
+
+def dsa_index_keys(p, x, cfg, positions):
+    """The indexer's key of each token: (B, S, index_head_dim)."""
+    k = apply_norm(p["idx_k_norm"], x @ p["idx_wk"].astype(x.dtype))
+    return _idx_rope(k[..., None, :], positions, cfg)[..., 0, :]
+
+
+def dsa_index_scores(p, x, q_src, k_idx, cfg, positions):
+    """The lightning indexer's score of every cached token for every
+    query, I[b, s, t] = sum_j w[b, s, j] relu(q[b, s, j] . k[b, t]),
+    (B, Sq, Sk); the weights w carry 1/sqrt(heads * head_dim)."""
+    x_ = cfg.dsa
+    q = jnp.einsum("bsr,rjc->bsjc", q_src, p["idx_wq"].astype(x.dtype))
+    q = _idx_rope(q, positions, cfg)
+    w = (x @ p["idx_ww"].astype(x.dtype)) * (
+        (x_.index_n_heads * x_.index_head_dim) ** -0.5)
+    s = jnp.einsum("bsjc,btc->bsjt", q, k_idx,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("bsj,bsjt->bst", w.astype(jnp.float32),
+                      jax.nn.relu(s))
+
+
+def dsa_select(scores, visible, topk: int):
+    """Each query's ``topk`` best-scored tokens among those it may see
+    (all of them when it sees fewer): a (B, Sq, Sk) mask."""
+    s = jnp.where(visible, scores, -jnp.inf)
+    kth = jax.lax.top_k(s, min(topk, s.shape[-1]))[0][..., -1:]
+    return visible & (s >= kth)
 
 
 def _mla_ckv(p, x, cfg, positions):
@@ -280,13 +347,15 @@ def _mla_ckv(p, x, cfg, positions):
 
 def mla_fwd(p, x, cfg: ModelConfig, positions, cache=None, pos=None):
     """Absorbed-matmul MLA.  Training/prefill when cache is None or a
-    fresh cache is produced; decode when (cache, pos) given."""
+    fresh cache is produced; decode when (cache, pos) given.  With DSA
+    (``cfg.dsa``) each query attends to its indexer-selected tokens only
+    and the cache holds the indexer's keys third."""
     m = cfg.mla
     B = x.shape[0]
     pos_vec = (None if pos is None else
                jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)))
-    q_nope, q_rope = _mla_q(p, x, cfg,
-                            positions if pos is None else pos_vec[:, None])
+    q_positions = positions if pos is None else pos_vec[:, None]
+    q_nope, q_rope, q_src = _mla_q(p, x, cfg, q_positions)
     # absorb W_uk into q: score space is the compressed rank r
     q_c = jnp.einsum("bshe,rhe->bshr", q_nope, p["w_uk"].astype(x.dtype))
     if pos is None:
@@ -295,6 +364,9 @@ def mla_fwd(p, x, cfg: ModelConfig, positions, cache=None, pos=None):
         q_pos = positions[0]
         causal = (k_pos[None, :] <= q_pos[:, None])[None]   # (1, Sq, Sk)
         new_cache = (c_kv, k_rope)
+        if cfg.dsa:
+            k_idx = dsa_index_keys(p, x, cfg, positions)
+            new_cache += (k_idx,)
     else:
         c_new, kr_new = _mla_ckv(p, x, cfg, pos_vec[:, None])
         b_idx = jnp.arange(B)
@@ -304,6 +376,14 @@ def mla_fwd(p, x, cfg: ModelConfig, positions, cache=None, pos=None):
         causal = (k_pos[None, None, :]
                   <= pos_vec[:, None, None])                # (B, 1, Sk)
         new_cache = (c_kv, k_rope)
+        if cfg.dsa:
+            ki_new = dsa_index_keys(p, x, cfg, pos_vec[:, None])
+            k_idx = cache[2].at[b_idx, pos_vec].set(ki_new[:, 0])
+            new_cache += (k_idx,)
+    if cfg.dsa:
+        causal = dsa_select(
+            dsa_index_scores(p, x, q_src, k_idx, cfg, q_positions),
+            causal, cfg.dsa.index_topk)
     scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     s = (jnp.einsum("bshr,bkr->bhsk", q_c, c_kv,
                     preferred_element_type=jnp.float32)

@@ -150,13 +150,23 @@ def block_fwd(p, x, cfg: ModelConfig, positions, *, mode="train",
 # Family: dense / moe / vlm decoder-only LM
 # ======================================================================
 def init_lm(cfg: ModelConfig, key):
-    ks = jax.random.split(key, 3)
+    ks = jax.random.split(key, 4)
     p, sp = {}, {}
     p["embed"], sp["embed"] = L.init_embedding(cfg, ks[0])
+    first = cfg.moe.first_dense if cfg.moe else 0
+    if first:
+        p["dense_blocks"], sp["dense_blocks"] = _vmap_init(
+            lambda k: init_block(cfg, k, moe_layer=False), first, ks[3])
     p["blocks"], sp["blocks"] = _vmap_init(
-        lambda k: init_block(cfg, k), cfg.num_layers, ks[1])
+        lambda k: init_block(cfg, k), cfg.num_layers - first, ks[1])
     p["ln_f"], sp["ln_f"] = L.init_norm(cfg, cfg.d_model)
     return _f32_to(p, jnp.dtype(cfg.dtype)), sp
+
+
+def _stacks(params) -> list:
+    """The decoder's stacked blocks in layer order: a MoE model's leading
+    dense-FFN blocks (``MoEConfig.first_dense``), then the rest."""
+    return [params[k] for k in ("dense_blocks", "blocks") if k in params]
 
 
 REMAT_POLICIES = {
@@ -184,8 +194,10 @@ def lm_hidden(params, x, cfg: ModelConfig, positions, *, remat=True,
 
     if remat:
         body = _remat(body, remat_policy)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               params["blocks"])
+    carry = (x, jnp.zeros((), jnp.float32))
+    for stack in _stacks(params):
+        carry, _ = jax.lax.scan(body, carry, stack)
+    x, aux = carry
     return L.apply_norm(params["ln_f"], x), aux
 
 
@@ -204,8 +216,9 @@ def lm_init_cache(cfg: ModelConfig, B: int, S: int, dtype):
     Lh = cfg.num_layers
     if cfg.mla:
         m = cfg.mla
-        return (jnp.zeros((Lh, B, S, m.kv_lora_rank), dtype),
-                jnp.zeros((Lh, B, S, m.qk_rope_head_dim), dtype))
+        widths = (m.kv_lora_rank, m.qk_rope_head_dim) + (
+            (cfg.dsa.index_head_dim,) if cfg.dsa else ())
+        return tuple(jnp.zeros((Lh, B, S, w), dtype) for w in widths)
     return (jnp.zeros((Lh, B, S, cfg.num_kv_heads, cfg.head_dim), dtype),
             jnp.zeros((Lh, B, S, cfg.num_kv_heads, cfg.head_dim), dtype))
 
@@ -213,7 +226,7 @@ def lm_init_cache(cfg: ModelConfig, B: int, S: int, dtype):
 def cache_specs(cfg: ModelConfig):
     """PartitionSpecs for the KV cache (kv heads->model, batch->data)."""
     if cfg.mla:
-        return (P(None, DATA, None, None), P(None, DATA, None, None))
+        return (P(None, DATA, None, None),) * (3 if cfg.dsa else 2)
     return (P(None, DATA, None, MODEL, None),
             P(None, DATA, None, MODEL, None))
 
@@ -232,18 +245,17 @@ def lm_prefill(params, tokens, cfg: ModelConfig, S_max: int,
         h, kv, _ = block_fwd(bp, h, cfg, positions, mode="prefill")
         return h, kv
 
-    x, kvs = jax.lax.scan(body, x, params["blocks"])
+    per_stack = []
+    for stack in _stacks(params):
+        x, kv = jax.lax.scan(body, x, stack)
+        per_stack.append(kv)
+    kvs = [jnp.concatenate(parts) for parts in zip(*per_stack)]
     x = L.apply_norm(params["ln_f"], x)
     logits = L.lm_logits(params["embed"], x[:, -1:, :], cfg)
     # place into S_max-sized cache
-    if cfg.mla:
-        c0, r0 = lm_init_cache(cfg, B, S_max, x.dtype)
-        cache = (jax.lax.dynamic_update_slice(c0, kvs[0], (0, 0, 0, 0)),
-                 jax.lax.dynamic_update_slice(r0, kvs[1], (0, 0, 0, 0)))
-    else:
-        k0, v0 = lm_init_cache(cfg, B, S_max, x.dtype)
-        cache = (jax.lax.dynamic_update_slice(k0, kvs[0], (0, 0, 0, 0, 0)),
-                 jax.lax.dynamic_update_slice(v0, kvs[1], (0, 0, 0, 0, 0)))
+    cache = tuple(jax.lax.dynamic_update_slice(c0, kv, (0,) * c0.ndim)
+                  for c0, kv in zip(lm_init_cache(cfg, B, S_max, x.dtype),
+                                    kvs))
     return logits, cache
 
 
@@ -257,7 +269,14 @@ def lm_decode_step(params, token, cache, pos, cfg: ModelConfig):
                                 cache=c, pos=pos)
         return h, new_c
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+    new_parts, at = [], 0
+    for stack in _stacks(params):
+        n = jax.tree.leaves(stack)[0].shape[0]
+        x, part = jax.lax.scan(
+            body, x, (stack, tuple(c[at:at + n] for c in cache)))
+        new_parts.append(part)
+        at += n
+    new_cache = tuple(jnp.concatenate(parts) for parts in zip(*new_parts))
     x = L.apply_norm(params["ln_f"], x)
     return L.lm_logits(params["embed"], x, cfg), new_cache
 

@@ -210,7 +210,7 @@ def test_dedupe_shapes_fanout():
                LayerMatmul("c", 8, 16, 32), LayerMatmul("d", 8, 16, 32)]
     unique, index = dedupe_shapes(entries)
     assert len(unique) == 2
-    assert [unique[i] for i in index] == [e.shape for e in entries]
+    assert [unique[i] for i in index] == [(*e.shape, None) for e in entries]
 
 
 def test_dedup_evals_counter():
@@ -339,13 +339,15 @@ def test_crossover_values_on_grid():
 # row path: every shape of an option on one candidate axis
 # ----------------------------------------------------------------------
 
-def _per_shape(option, shapes, *, check_capacity=False):
+def _per_shape(option, rows, *, check_capacity=False):
     """The sweep's evaluation through the per-shape network path: one
-    single-candidate population, and one program call, per shape."""
+    single-candidate population, and one program call, per shape (these
+    rows carry no densities of their own)."""
+    assert all(dens is None for *_, dens in rows)
     outs = Sparseloop(option.design).evaluate_network(
         [matmul(M, K, N, densities=option.densities)
-         for M, K, N in shapes],
-        [[tpu_mapping(M, K, N)] for M, K, N in shapes],
+         for M, K, N, _ in rows],
+        [[tpu_mapping(M, K, N)] for M, K, N, _ in rows],
         check_capacity=check_capacity)
     return [{k: float(o[k][0]) for k in ("cycles", "energy_pj", "edp")}
             for o in outs]

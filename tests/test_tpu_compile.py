@@ -131,10 +131,13 @@ def test_bucket_program_compiles_for_v5e_at_1024(one_chip):
     assert compiled.memory_analysis() is not None
 
 
-def test_row_program_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("option", ["nm-2:4", "dsa-skip"])
+def test_row_program_compiles_for_v5e(one_chip, option):
     """The bucket program's row variant, as the fleet sweep calls it:
-    one block of 2:4 shapes, each row with its own workload params (the
-    density kind's switch is then vmapped too), still one gammaln."""
+    one block of 2:4 shapes, or of DSA value products at cache lengths
+    up to 163840 (the selection's branch compiled in), each row with its
+    own workload params (the density kind's switch is then vmapped too),
+    still one gammaln."""
     import jax
     from repro.core.advisor import tpu_mapping
     from repro.core.batched import (ROW_BLOCK, bucket_for, lower_nests,
@@ -142,16 +145,26 @@ def test_row_program_compiles_for_v5e(one_chip):
                                     stack_workload_params, template_of)
     from repro.core.engine import Sparseloop
     from repro.core.workload import matmul
-    from repro.fleet.sweep import nm_option
+    from repro.fleet.sweep import dsa_option, nm_option
 
-    opt = nm_option(2, 4)
-    shapes = [(8 * (i + 1), 512 * (1 + i % 4), 1024)
-              for i in range(ROW_BLOCK)]
-    wls = [matmul(*s, densities=opt.densities) for s in shapes]
+    if option == "nm-2:4":
+        opt = nm_option(2, 4)
+        shapes = [(8 * (i + 1), 512 * (1 + i % 4), 1024)
+                  for i in range(ROW_BLOCK)]
+        wls = [matmul(*s, densities=opt.densities) for s in shapes]
+        design = opt.design
+    else:
+        ctx = [4096 * (1 + i % 40) for i in range(ROW_BLOCK)]
+        shapes = [(8, L, 512) for L in ctx]
+        wls = [matmul(*s, densities={"B": ("selected", {
+            "rank": "k", "L": L, "k": 2048, "fibre": 512})})
+            for s, L in zip(shapes, ctx)]
+        design = dsa_option().design_for(wls[0].densities)
     nests = [tpu_mapping(*s) for s in shapes]
     bucket = bucket_for(template_of(nests[0]), tuple(wls[0].rank_bounds))
-    bm = Sparseloop(opt.design).bucketed_model(wls[0], bucket,
-                                               check_capacity=False)
+    bm = Sparseloop(design).bucketed_model(wls[0], bucket,
+                                           check_capacity=False)
+    assert bm.caps.select == (option == "dsa-skip")
     bounds, ids, _ = lower_nests(bucket, nests, range(ROW_BLOCK))
     wp = stack_workload_params(
         [pack_workload_params(wl, bm.caps) for wl in wls])
